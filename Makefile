@@ -31,7 +31,7 @@ bench:
 # Build the native C++ reference classifier explicitly (normally built
 # on demand by infw.backend.cpu_ref — the bpf2go-generate analogue).
 native:
-	$(MAKE) -C infw/backend/native
+	$(PY) -c "from infw.backend.cpu_ref import load_library; load_library()"
 
 # Single-chip compile check of the flagship forward step, then the
 # static hot-path audit (x64 leaks, host callbacks, recompile lint,
@@ -90,7 +90,7 @@ lint:
 #      process, the flags act at trace time.
 # The full defect inventory is declarative (infw.analysis.defects);
 # `infw_lint acceptance` loops it end to end.
-# Must be green before any bench record is published (benchruns/README).
+# Must be green before any bench record is published.
 state-check:
 	$(MESH_ENV) $(PY) tools/infw_lint.py state --strict
 	$(MESH_ENV) $(PY) tools/infw_lint.py state --inject-defect
@@ -323,7 +323,7 @@ payload-bench:
 pipeline-bench:
 	$(MESH_ENV) $(PY) bench.py --pipeline-bench
 
-# Bench behind the static gate (benchruns/README.md: jaxpr drift must
+# Bench behind the static gate (jaxpr drift must
 # not silently change what the bench measures).  `make bench` itself is
 # left untouched — its stdout is a driver contract.
 bench-checked: static-check build-bench slo-bench churn-bench tenant-bench splice-bench flow-bench resident-bench telemetry-bench mlscore-bench payload-bench pipeline-bench bench

@@ -149,6 +149,10 @@ def build_plan(bundle: dict, subs: dict, extra_args: dict | None = None,
         # and polls for a NodeState that never appears).
         if subs.get("node-name"):
             env["NODE_NAME"] = str(subs["node-name"])
+        # --backend names the whole composition too: the manager renders
+        # the daemon backend from INFW_BACKEND without probing a device
+        if subs.get("backend"):
+            env["INFW_BACKEND"] = str(subs["backend"])
         for var, default in ENV_DEFAULTS.items():
             env.setdefault(var, default)
         # The bundle's envFromFlags contract: launcher flags become the

@@ -205,7 +205,7 @@ class Finding:
     subject: str         # suppression-matchable: entry:prim:tag
     message: str
     eqn: str = ""
-    region: str = ""     # e.g. "pjit/scan.body"
+    region: str = ""     # e.g. "jit/scan.body"
     interval: str = ""
     extent: str = ""
     count: int = 1       # identical findings folded per entry
@@ -720,9 +720,9 @@ def _absval_of_literal(val) -> AbsVal:
 
 
 def _read(env: Dict, v) -> AbsVal:
-    import jax.core as jcore
+    from jax.extend.core import Literal
 
-    if isinstance(v, jcore.Literal):
+    if isinstance(v, Literal):
         return _absval_of_literal(v.val)
     return env[v]
 
@@ -831,7 +831,7 @@ def _eqn_transfer(eqn, ins: List[AbsVal], ctx: _Ctx,
     params = eqn.params
 
     # -- structured control flow / calls --
-    if prim == "pjit":
+    if prim == "jit":
         sub = params.get("jaxpr")
         if sub is not None:
             return interp_closed_jaxpr(sub, ins, ctx, region)

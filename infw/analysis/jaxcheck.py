@@ -106,7 +106,7 @@ class EntryReport:
 
 
 def _iter_eqns(jaxpr, _depth=0):
-    """Yield every eqn in a jaxpr including nested call/scan/cond/pjit
+    """Yield every eqn in a jaxpr including nested call/scan/cond/jit
     bodies (depth-bounded defensively)."""
     if _depth > 32:
         return

@@ -1,13 +1,14 @@
 """ctypes bindings + Classifier wrapper for the native C++ reference
 classifier (native/classifier.cpp).
 
-The shared library is built on demand with g++ (cached by source mtime) —
+The shared library is built on demand with g++ (cached by source hash) —
 the framework's analogue of the reference's bpf2go build step
 (/root/reference/pkg/ebpf/ingress_node_firewall_loader.go:53).
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,22 +23,31 @@ from .base import ClassifyOutput, PendingClassify, StatsAccumulator
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "native")
 _SRC = os.path.join(_NATIVE_DIR, "classifier.cpp")
-_LIB = os.path.join(_NATIVE_DIR, "_build", "libinfwref.so")
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
 def _build_library() -> str:
-    os.makedirs(os.path.dirname(_LIB), exist_ok=True)
-    if (not os.path.exists(_LIB)) or os.path.getmtime(_LIB) < os.path.getmtime(_SRC):
+    """Path of the shared library built from the committed source.  The
+    file name carries a hash of ``classifier.cpp``, so a library built
+    from other source (a stale copy carried along with a checkout) is
+    never loaded.  The build lands under a temporary name and is renamed
+    into place, so concurrent processes never load a half-written file."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    lib = os.path.join(_NATIVE_DIR, "_build", f"libinfwref-{digest}.so")
+    if not os.path.exists(lib):
+        os.makedirs(os.path.dirname(lib), exist_ok=True)
+        tmp = f"{lib}.tmp.{os.getpid()}"
         subprocess.run(
             ["g++", "-O2", "-fPIC", "-std=c++17", "-Wall", "-pthread",
-             "-shared", "-o", _LIB, _SRC],
+             "-shared", "-o", tmp, _SRC],
             check=True,
             capture_output=True,
         )
-    return _LIB
+        os.replace(tmp, lib)
+    return lib
 
 
 _ABI_VERSION = 4
@@ -50,12 +60,11 @@ def load_library() -> ctypes.CDLL:
             lib = ctypes.CDLL(_build_library())
             lib.infw_abi_version.restype = ctypes.c_int32
             if lib.infw_abi_version() != _ABI_VERSION:
-                # Stale prebuilt .so whose mtime defeated the rebuild gate
-                # (artifact cache, cp -p): force one rebuild from source
-                # instead of binding symbols that may not exist.
-                os.remove(_LIB)
-                lib = ctypes.CDLL(_build_library())
-                lib.infw_abi_version.restype = ctypes.c_int32
+                raise RuntimeError(
+                    f"native reference library ABI {lib.infw_abi_version()}"
+                    f" != {_ABI_VERSION}: classifier.cpp and cpu_ref.py "
+                    "disagree"
+                )
             i32p = ctypes.POINTER(ctypes.c_int32)
             u32p = ctypes.POINTER(ctypes.c_uint32)
             u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -82,7 +91,6 @@ def load_library() -> ctypes.CDLL:
             lib.infw_encode_delta.argtypes = [
                 ctypes.c_int64, u32p, u8p, u32p, i32p, i64p, i32p,
             ]
-            assert lib.infw_abi_version() == _ABI_VERSION
             _lib = lib
         return _lib
 

@@ -320,6 +320,7 @@ class MeshTpuClassifier(TpuClassifier):
             if self._rules_shards > 1:
                 fn = meshmod.jitted_mesh_wire(mesh, "dense-sharded", dev)
             else:
+                self._note_pallas("pallas-dense", n)
                 fn = meshmod.jitted_mesh_wire(
                     mesh, "pallas-dense", dev,
                     interpret=self._interpret, block_b=block_b,
@@ -328,6 +329,7 @@ class MeshTpuClassifier(TpuClassifier):
         elif walk_dev is not None and ov_dev is None:
             # Fused Pallas deep walk per shard — same kernel, same
             # overlay exclusion, as the single-chip dispatch.
+            self._note_pallas("pallas-walk", n)
             fn = meshmod.jitted_mesh_wire(
                 mesh, "walk", walk_dev, interpret=self._interpret
             )

@@ -312,6 +312,7 @@ class TpuClassifier:
         # per-format H2D accounting {fmt: [packets, payload bytes]} — the
         # bench reads this to put bytes/packet in the replay record
         self._wire_counts = {}
+        self._pallas_counts = {}  # kernel -> packets (pallas_stats)
         self._tables: Optional[CompiledTables] = None
         # (path, dev tables, block_b|None, wide_rids, overlay dev|None,
         #  fused walk dev|None)
@@ -1705,6 +1706,20 @@ class TpuClassifier:
         with self._lock:
             return {k: tuple(v) for k, v in self._wire_counts.items()}
 
+    def _note_pallas(self, kernel: str, n: int) -> None:
+        with self._lock:
+            self._pallas_counts[kernel] = self._pallas_counts.get(kernel, 0) + n
+
+    def pallas_stats(self):
+        """{Pallas kernel: packets dispatched to it} since construction."""
+        with self._lock:
+            return dict(self._pallas_counts)
+
+    @property
+    def interpret(self) -> bool:
+        """True when the Pallas kernels run in interpret mode (CPU)."""
+        return self._interpret
+
     @staticmethod
     def _wire4_pkt_len(wire4_np: np.ndarray) -> np.ndarray:
         """Full pkt_len reconstruction from the 4-word wire (pack_wire
@@ -1810,6 +1825,7 @@ class TpuClassifier:
         # RPC pays the link's sync floor, so two arrays per chunk would
         # double the per-chunk latency cost.
         if path == "dense":
+            self._note_pallas("pallas-dense", n)
             fused = pallas_dense.jitted_classify_pallas_wire_fused(
                 self._interpret, block_b
             )(dev, wire)
@@ -1822,6 +1838,7 @@ class TpuClassifier:
             cdev, d_max = dev
             if walk_dev is not None and ov_dev is None:
                 wt, dw = walk_dev
+                self._note_pallas("pallas-cwalk", n)
                 fused = pallas_walk.jitted_classify_cwalk_wire_fused(
                     dw, self._interpret
                 )(wt, wire)
@@ -1840,6 +1857,7 @@ class TpuClassifier:
             # VMEM-resident — no per-level HBM gather excursions.  The
             # overlay combine needs the XLA walk's score plumbing, so
             # overlay generations keep the XLA path for this class.
+            self._note_pallas("pallas-walk", n)
             fused = pallas_walk.jitted_classify_walk_wire_fused(
                 self._interpret
             )(walk_dev, wire)

@@ -33,6 +33,7 @@ import mmap
 import os
 import signal
 import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -625,6 +626,10 @@ class Daemon:
         self.superbatch_k = max(1, int(superbatch_k))
         self.ingest_ring = None
         self._ring_inflight: deque = deque()
+        #: optional callable(seq, results) receiving each ring record's
+        #: packed per-packet verdicts once they materialize — the ring
+        #: path's counterpart of the file path's out/*.verdicts.bin
+        self.ring_verdict_sink = None
         if ring:
             from .ring import IngestRing
 
@@ -722,9 +727,7 @@ class Daemon:
             os.makedirs(d, exist_ok=True)
 
         if backend == "tpu":
-            platform_mod.enable_jax_compile_cache(
-                os.path.join(state_dir, "jax-cache")
-            )
+            platform_mod.enable_compile_cache()
 
         # Per-daemon metrics registry (controller-runtime gives each
         # manager its own, statistics.go:79-86): /metrics serves whatever
@@ -1812,9 +1815,11 @@ class Daemon:
     def _ring_drain_one(self) -> None:
         chunk, pending, trace = self._ring_inflight.popleft()
         try:
-            pending.result()
+            out = pending.result()
             if trace is not None:
                 trace.mark("materialize")
+            if self.ring_verdict_sink is not None:
+                self.ring_verdict_sink(chunk.seq, out.results)
         except Exception as e:
             log.error("ring ingest classify failed: %s", e)
         finally:
@@ -1826,10 +1831,10 @@ class Daemon:
     def _maybe_prewarm_ladder(self, clf) -> None:
         """Pre-warm every batch-size ladder shape against the CURRENT
         table generation, once per generation: shape-driven jit
-        specialization (and a tunneled deployment's per-executable
-        first-dispatch cost) lands here, never inside a serving-path
-        latency budget.  Covers batch=32 (the BENCH_r05 small-batch
-        anomaly shape) and every depth-steering class."""
+        specialization (and each executable's first-dispatch cost)
+        lands here, never inside a serving-path latency budget.  Covers
+        batch=32 (the smallest ladder rung) and every depth-steering
+        class."""
         gen = (id(clf), id(clf.tables), getattr(clf, "_depth_gen", 0))
         if gen == self._prewarmed_gen:
             return
@@ -2684,6 +2689,16 @@ def main(argv: Optional[List[str]] = None) -> int:
         from .parallel.multihost import init_distributed
 
         init_distributed()
+        # --backend tpu on a host where JAX came up on another platform
+        # would serve through the CPU interpreter without saying so; only
+        # an explicit JAX_PLATFORMS=cpu (the tests) may run it there.
+        info = platform_mod.get_platform_info()
+        cpu_asked = os.environ.get("JAX_PLATFORMS", "").strip() == "cpu"
+        if info.backend != "tpu" and not cpu_asked:
+            print(f"infw-daemon: --backend tpu, but JAX came up on "
+                  f"{info.backend!r} (set JAX_PLATFORMS=cpu to run the "
+                  "device path on the CPU on purpose)", file=sys.stderr)
+            return 1
     debug = os.environ.get("ENABLE_LPM_LOOKUP_DBG", "0") not in ("0", "", "false")
     daemon = Daemon(
         state_dir=args.state_dir,

@@ -518,9 +518,7 @@ def _build_arena_splice_wire(b: int):
 
 
 def _build_pallas_arena_walk(b: int):
-    import jax
-
-    from . import pallas_walk
+    from . import pallas_dense, pallas_walk
 
     alloc = _fixture_arena("ctrie")
     spec = alloc.spec
@@ -530,7 +528,7 @@ def _build_pallas_arena_walk(b: int):
             "arena node pool exceeds the paged-walk VMEM budget"
         )
     fn = pallas_walk.jitted_classify_arena_cwalk_wire_fused(
-        spec.pages, spec.d_max, pallas_walk.default_interpret()
+        spec.pages, spec.d_max, pallas_dense.default_interpret()
     )
     wire, tenant = _fixture_arena_wire(b)
     return fn, (alloc.arena, planes, wire, tenant)
@@ -1062,12 +1060,12 @@ def _build_mesh_sharded_trie(b: int):
 
 def _build_mesh_walk(b: int):
     from ..parallel import mesh as meshmod
-    from . import pallas_walk
+    from . import pallas_dense
 
     dev = _fixture_mesh_walk_tables()
     fn = meshmod.jitted_mesh_wire(
         _fixture_mesh(1), "walk", dev,
-        interpret=pallas_walk.default_interpret(),
+        interpret=pallas_dense.default_interpret(),
     )
     return fn, (dev, _fixture_mesh_wire(b, 1))
 

@@ -86,8 +86,8 @@ def _inject_mlquant_bug() -> bool:
 SCORE_KEY_WORDS = 6
 
 #: the fixed feature schema (index -> meaning); every feature is int32
-#: and NONE reads attack ground-truth labels (the label-discipline note
-#: in benchruns/README.md) — verdicts here are RULE verdicts, computed
+#: and NONE reads attack ground-truth labels (they exist for the
+#: measuring side only) — verdicts here are RULE verdicts, computed
 #: before any enforcement:
 #:   0 src_pkts       source-row packet count (post-update, sat-clamped)
 #:   1 src_syns       source-row pure-SYN count

@@ -477,6 +477,19 @@ def _pc32(x: jax.Array) -> jax.Array:
     return jax.lax.shift_right_logical(x * 0x01010101, 24)
 
 
+def _le_u32(rows: jax.Array, c: int) -> jax.Array:
+    """The little-endian u32 word in byte lanes c..c+3 of an int32 byte
+    matrix.  Multiplies, not shifts: on the TPU, Mosaic returns wrong
+    words for the shift-left form over single-lane slices, which interpret
+    mode does not show (wrapping int32 multiplies give the same bits)."""
+    return (
+        rows[:, c : c + 1]
+        + rows[:, c + 1 : c + 2] * 256
+        + rows[:, c + 2 : c + 3] * 65536
+        + rows[:, c + 3 : c + 4] * 16777216
+    )
+
+
 def _make_walk_kernel(n_levels: int, fused_tail: bool):
     def kernel(meta_ref, words_ref, *refs):
         level_refs = refs[:n_levels]
@@ -516,12 +529,7 @@ def _make_walk_kernel(n_levels: int, fused_tail: bool):
             ) + jnp.where(live, 128, 0)  # un-bias; dead rows stay zero
 
             def u32(c, _r=rowb):
-                return (
-                    _r[:, c : c + 1]
-                    | (_r[:, c + 1 : c + 2] << 8)
-                    | (_r[:, c + 2 : c + 3] << 16)
-                    | (_r[:, c + 3 : c + 4] << 24)
-                )
+                return _le_u32(_r, c)
 
             child_base = u32(0)
             target_base = u32(4)
@@ -717,11 +725,6 @@ def jitted_classify_walk_wire_fused(interpret: bool, block_b: int = BLOCK_B):
         )
 
     return jax.jit(f)
-
-
-def default_interpret() -> bool:
-    """Interpret mode everywhere except real TPU backends."""
-    return jax.default_backend() != "tpu"
 
 
 # --- fused COMPRESSED walk (skip-node descent over the merged cpoptrie) -----
@@ -957,20 +960,15 @@ def _make_cwalk_kernel(d_max: int):
             ) + jnp.where(live, 128, 0)
 
             def u32(c, _r=rowb):
-                return (
-                    _r[:, c : c + 1]
-                    | (_r[:, c + 1 : c + 2] << 8)
-                    | (_r[:, c + 2 : c + 3] << 16)
-                    | (_r[:, c + 3 : c + 4] << 24)
-                )
+                return _le_u32(_r, c)
 
             child_base = u32(0)
             target_base = u32(4)
             skip_len = u32(8)
             skip_bits = u32(12)
-            skip_ok = jnp.where(
-                skip_len > 0, extract(pos, skip_len) == skip_bits, True
-            )
+            # (skip_len == 0) | match, not where(.., bool, True): Mosaic
+            # cannot lower a select whose operands are i1 vectors.
+            skip_ok = (skip_len == 0) | (extract(pos, skip_len) == skip_bits)
             live = live & skip_ok
             pos = pos + skip_len
             nib = extract(pos, jnp.full((Bb, 1), 8, jnp.int32))

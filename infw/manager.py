@@ -3,9 +3,9 @@ admission webhook, and the NodeState export path.
 
 Equivalent of the reference's manager binary (/root/reference/main.go):
 env contract DAEMONSET_IMAGE / DAEMONSET_NAMESPACE (:87-99), webhook
-registration behind a toggle (:142-147), platform probe (:149-154),
-controller setup with watches (:132-140, 155-164), healthz endpoint and
-blocking run loop (:101-126, 177).
+registration behind a toggle (:142-147), controller setup with watches
+(:132-140, 155-164), healthz endpoint and blocking run loop (:101-126,
+177).
 
 The watch->workqueue->reconcile shape mirrors controller-runtime: events
 coalesce in a debounced queue, reconciles run on a worker thread, and a
@@ -17,6 +17,11 @@ write/delete is mirrored to ``<export_dir>/nodestates/<node>.json`` — the
 file protocol the daemon watches — so manager and daemons compose across
 process boundaries the way the reference's manager and DaemonSet compose
 through the k8s API.
+
+The manager never starts a JAX backend: on a TPU host the process that
+initializes JAX holds the chip, and the daemon started beside it must
+get it.  The daemon backend it renders comes from ``INFW_BACKEND`` (the
+variable the launcher and the daemon read), default ``tpu``.
 """
 from __future__ import annotations
 
@@ -33,7 +38,6 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import List, Optional
 
 from ._threads import spawn
-from . import platform as platform_mod
 from . import validate
 from .controllers import (
     DEFAULT_CONFIG_NAME,
@@ -93,11 +97,11 @@ class Manager:
     ) -> None:
         self.store = store if store is not None else InMemoryStore()
         self.namespace = namespace
-        self.platform = platform_mod.get_platform_info()
-        backend = "tpu" if self.platform.is_tpu else "cpu"
+        self.backend = os.environ.get("INFW_BACKEND") or "tpu"
         self.fanout = IngressNodeFirewallReconciler(self.store, namespace=namespace)
         self.config = IngressNodeFirewallConfigReconciler(
-            self.store, namespace=namespace, daemon_image=daemon_image, backend=backend
+            self.store, namespace=namespace, daemon_image=daemon_image,
+            backend=self.backend,
         )
         self.metrics_port = metrics_port
         self.health_port = health_port
@@ -540,9 +544,8 @@ class Manager:
         self.enqueue_fanout()
         self.enqueue_config(DEFAULT_CONFIG_NAME)
         log.info(
-            "manager started namespace=%s platform=%s devices=%d leader=%s",
-            self.namespace, self.platform.backend, self.platform.num_devices,
-            self.is_leader,
+            "manager started namespace=%s daemon-backend=%s leader=%s",
+            self.namespace, self.backend, self.is_leader,
         )
         return True
 

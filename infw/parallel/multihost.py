@@ -81,9 +81,9 @@ def init_distributed(
         or ""
     )
     if platforms.startswith("cpu"):
-        from .compat import enable_cpu_collectives
-
-        enable_cpu_collectives()
+        # cross-process collectives on the CPU backend (Gloo, the stand-in
+        # for DCN in the 2-process tests and the compose multi-host dryrun)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coord, num_processes=n, process_id=pid
     )

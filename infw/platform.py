@@ -7,13 +7,17 @@ probes the discovery API for the `route.openshift.io` group to decide
 OpenShift vs vanilla k8s, :94-101, consumed at
 ingressnodefirewallconfig_controller.go:138).  Here the meaningful
 environment facts are the accelerator platform: which JAX backend is
-live, the device kind, and how many chips are attached — consumed to pick
-the daemon backend and mesh shape.
+live, the device kind, and how many chips are attached — consumed by the
+daemon's ``--backend tpu`` launch check and by ``chip_smoke.py``.  Only a
+process that is to hold the chip may probe it (the manager never does).
+
+This module also places JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional
 
 
 @dataclass(frozen=True)
@@ -33,45 +37,43 @@ class PlatformInfo:
 
 
 def get_platform_info() -> PlatformInfo:
-    """GetPlatformInfo (platform.go:34-104).  Probes lazily and degrades
-    to a CPU-only report if JAX cannot initialize a backend."""
-    try:
-        import jax
+    """GetPlatformInfo (platform.go:34-104): probe the live JAX backend.
+    Raises when JAX cannot bring one up — a failed probe must never read
+    as a CPU host.  Probing initializes the backend, which then holds the
+    accelerator for this process."""
+    import jax
 
-        devices = jax.devices()
-        backend = jax.default_backend()
-        kind = devices[0].device_kind if devices else ""
-        platforms = sorted({d.platform for d in devices})
-        return PlatformInfo(
-            backend=backend,
-            device_kind=kind,
-            num_devices=len(devices),
-            device_platforms=platforms,
-        )
-    except Exception:
-        return PlatformInfo(
-            backend="cpu", device_kind="", num_devices=0, device_platforms=[]
-        )
+    devices = jax.devices()
+    return PlatformInfo(
+        backend=jax.default_backend(),
+        device_kind=devices[0].device_kind,
+        num_devices=len(devices),
+        device_platforms=sorted({d.platform for d in devices}),
+    )
 
 
-def enable_jax_compile_cache(cache_dir: str) -> None:
-    """Persistent XLA compilation cache: a restarted daemon (or repeated
-    bench run) skips the 30-60s first-compile of its executables — they
-    rebuild from the on-disk cache in ~100s of ms.  Best effort: an old
-    jax without the option, or an unwritable dir, must never stop the
-    dataplane."""
-    import logging
-    import os
+#: the checkout's own compile-cache directory (listed in .gitignore)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
+)
 
-    try:
-        import jax
 
-        os.makedirs(cache_dir, exist_ok=True)
+def enable_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache when the backend is a
+    TPU, so a restarted daemon or a repeated run reloads its executables
+    instead of compiling them again.  The directory is
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads it itself,
+    and no other directory is set here), else ``<checkout>/.jax_cache``.
+    Returns the directory in use, or None off the TPU."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = CHECKOUT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # cache every executable, however fast its compile was
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # pragma: no cover - depends on jax build
-        logging.getLogger("infw.platform").warning(
-            "jax compilation cache unavailable: %s", e
-        )
+    # cache every executable, however fast its compile was
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir

@@ -466,6 +466,26 @@ class IngestRing:
         }
 
 
+def push_batch_slice(ring: IngestRing, batch, lo: int, hi: int,
+                     payload: Optional[np.ndarray] = None,
+                     payload_len: Optional[np.ndarray] = None,
+                     timeout: Optional[float] = None) -> int:
+    """Producer side of one ring record: pack packets ``[lo, hi)`` of a
+    PacketBatch (``pack_wire_subset``, the native fused pack) and publish
+    them, with the batch's TCP flags and an optional payload-prefix
+    column sliced alongside.  Blocks while the ring is full, up to
+    ``timeout`` seconds.  Returns the record's sequence number."""
+    wire, v4_only = batch.pack_wire_subset(np.arange(lo, hi, dtype=np.int64))
+    flags = getattr(batch, "tcp_flags", None)
+    return ring.push(
+        wire, v4_only=v4_only,
+        tcp_flags=None if flags is None else flags[lo:hi],
+        payload=None if payload is None else payload[lo:hi],
+        payload_len=None if payload_len is None else payload_len[lo:hi],
+        timeout=timeout,
+    )
+
+
 def ring_path(state_dir: str) -> str:
     """The daemon's default ring location under its state dir."""
     return os.path.join(state_dir, "ingest.ring")

@@ -535,3 +535,17 @@ def test_cross_file_batched_ingest_tpu_backend(tmp_path):
         assert snap[1, 2] == 70  # 40 + 30 denies across merged jobs
     finally:
         d.stop()
+
+
+def test_tpu_backend_refused_where_jax_is_not_on_a_tpu(tmp_path, monkeypatch,
+                                                       capsys):
+    """--backend tpu on a host where JAX came up on the CPU exits
+    non-zero, unless JAX_PLATFORMS=cpu asked for the CPU on purpose (as
+    these tests do: see the launch-validation tests elsewhere)."""
+    from infw.daemon import main as daemon_main
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    rc = daemon_main(["--state-dir", str(tmp_path), "--node-name", "n",
+                      "--backend", "tpu"])
+    assert rc == 1
+    assert "JAX_PLATFORMS=cpu" in capsys.readouterr().err

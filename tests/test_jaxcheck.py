@@ -69,7 +69,7 @@ def test_wide_dtype_detector():
     def leaky(x):
         return x.astype(jnp.float64) * 2.0
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(leaky)(np.ones(4, np.float32))
         findings = jaxcheck.check_wide_dtypes("t", jaxpr)
     assert findings and findings[0].check == "x64-leak"

@@ -489,3 +489,58 @@ def test_apply_dir_config_kind_and_unsupported(tmp_path):
         assert any("unsupported kind" in e for e in st["errors"])
     finally:
         m.stop()
+
+
+def test_manager_process_never_imports_jax(tmp_path):
+    """`python -m infw.manager` leaves the chip to the daemon started
+    beside it: JAX is made unimportable in the child, and the manager
+    must still come up, serve and exit cleanly on SIGTERM."""
+    import signal
+    import subprocess
+    import sys
+
+    fake = tmp_path / "fakejax" / "jax"
+    fake.mkdir(parents=True)
+    marker = tmp_path / "jax-imported"
+    (fake / "__init__.py").write_text(
+        f"open({str(marker)!r}, 'w').close()\n"
+        "raise ImportError('the manager must not import jax')\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(fake.parent), repo]))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "infw.manager",
+         "--export-dir", str(tmp_path / "state"), "--lease-file", "none",
+         "--namespace", "ns", "--daemon-image", "img",
+         "--metrics-port", "0", "--health-port", "0"],
+        env=env, stderr=subprocess.PIPE, text=True,
+    )
+    try:
+        deadline = time.time() + 30
+        while (time.time() < deadline and proc.poll() is None
+               and not (tmp_path / "state" / "nodestates").exists()):
+            time.sleep(0.1)
+        time.sleep(1.0)
+        assert proc.poll() is None, proc.stderr.read()
+        proc.send_signal(signal.SIGTERM)
+        _out, err = proc.communicate(timeout=30)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    assert proc.returncode == 0, err
+    assert "manager started" in err
+    assert not marker.exists()
+
+
+def test_platform_probe_raises_when_jax_has_no_backend(monkeypatch):
+    """A failed probe must not read as a CPU host."""
+    import jax
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError):
+        get_platform_info()

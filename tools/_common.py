@@ -27,18 +27,13 @@ def setup_repo_path() -> str:
     return _REPO_ROOT
 
 
-def jax_setup(compile_cache: Optional[str] = "/tmp/infw-jax-cache") -> bool:
-    """Import jax, enable the persistent compile cache on real TPU, and
-    return ``on_tpu``.  Call after setup_repo_path()."""
+def jax_setup() -> bool:
+    """Import jax, enable the persistent compile cache on real TPU
+    (infw.platform.enable_compile_cache), and return ``on_tpu``."""
     setup_repo_path()
-    import jax
+    from infw.platform import enable_compile_cache
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and compile_cache:
-        from infw.platform import enable_jax_compile_cache
-
-        enable_jax_compile_cache(compile_cache)
-    return on_tpu
+    return enable_compile_cache() is not None
 
 
 def scale_args(

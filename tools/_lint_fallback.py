@@ -20,7 +20,7 @@ from typing import Iterator, List, Set, Tuple
 
 DEFAULT_ROOTS = ("infw", "tools", "tests", "deploy", "bench.py",
                  "__graft_entry__.py")
-EXCLUDE_DIRS = {"__pycache__", ".git", "benchruns", "testruns", "_build"}
+EXCLUDE_DIRS = {"__pycache__", ".git", "testruns", "_build"}
 
 
 def iter_py_files(roots) -> Iterator[str]:

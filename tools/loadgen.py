@@ -237,8 +237,8 @@ def inject_attack(rng: np.random.Generator, c: dict, n: int, mode: str,
         # — the onset record index, one hex bitmap of attack lanes per
         # dropped file / ring record (decode_attack_labels), and the
         # lane->attacker assignment stride (attack_lane_src_ids).
-        # Features must never read these (benchruns/README.md label
-        # discipline); they exist for the measuring consumer only.
+        # Features must never read these (label discipline); they
+        # exist for the measuring consumer only.
         "labels": {
             "onset_record": int(start) // cp,
             "attack_src_stride": int(n_src),
@@ -311,7 +311,7 @@ def _ring_main(args, rng, offs) -> int:
     first packet's scheduled arrival time (open-loop; a full ring blocks
     and the stall is reported as schedule lag, never silently absorbed
     into a stretched offered load)."""
-    from infw.ring import IngestRing
+    from infw.ring import IngestRing, push_batch_slice
 
     batch, n_flows, attack_meta = synth_wire_batch(
         rng, args.n, args.v6_fraction, args.ifindex,
@@ -365,30 +365,8 @@ def _ring_main(args, rng, offs) -> int:
             worst_lag = max(worst_lag, lag)
             worst_producer_lag = max(worst_producer_lag, lag - blocked_s)
         lo, hi = i * fp, min((i + 1) * fp, args.n)
-        # fused subset pack straight from the SoA columns, then one
-        # in-place copy into the reserved (mapped) slot — the producer
-        # allocates nothing per record beyond the pack scratch
-        wire, v4_only = batch.pack_wire_subset(
-            np.arange(lo, hi, dtype=np.int64)
-        )
-        flags = getattr(batch, "tcp_flags", None)
-        if pay is None:
-            wv, fl, token = ring.reserve(
-                wire.shape[0], wire.shape[1],
-                with_flags=flags is not None, timeout=30.0,
-            )
-        else:
-            wv, fl, pv, lv, token = ring.reserve(
-                wire.shape[0], wire.shape[1],
-                with_flags=flags is not None,
-                payload_width=pay.shape[1], timeout=30.0,
-            )
-            np.copyto(pv, pay[lo:hi])
-            np.copyto(lv, plens[lo:hi])
-        np.copyto(wv, wire)
-        if fl is not None and flags is not None:
-            np.copyto(fl, flags[lo:hi])
-        ring.commit(token, v4_only=v4_only)
+        push_batch_slice(ring, batch, lo, hi, payload=pay,
+                         payload_len=plens, timeout=30.0)
         blocked_s = ring.counter_values()["ring_blocked_us_total"] / 1e6
     done = time.monotonic() - t0
     print(json.dumps({
