@@ -38,16 +38,26 @@ class PendingClassify:
     caller streaming batches keeps several in flight so H2D transfer, kernel
     and D2H readback of consecutive batches overlap.  `result()` blocks
     until this batch's outputs are host-resident and applies the stats
-    increment exactly once."""
+    increment exactly once.
 
-    def __init__(self, materialize) -> None:
+    ``readback``, where a backend gives one, blocks until the device
+    outputs are host-resident and does nothing else; ``wait()`` calls it,
+    so a tracer can time the wait on the device apart from the host work
+    that ``result()`` does after it."""
+
+    def __init__(self, materialize, readback=None) -> None:
         self._materialize = materialize
+        self._readback = readback
         self._out: Optional[ClassifyOutput] = None
+
+    def wait(self) -> None:
+        if self._out is None and self._readback is not None:
+            self._readback()
 
     def result(self) -> ClassifyOutput:
         if self._out is None:
             self._out = self._materialize()
-            self._materialize = None  # drop device refs
+            self._materialize = self._readback = None  # drop device refs
         return self._out
 
 

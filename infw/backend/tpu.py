@@ -1378,7 +1378,11 @@ class TpuClassifier:
                     results=results, xdp=xdp, stats_delta=stats_delta
                 )
 
-            return PendingClassify(materialize)
+            # np.asarray keeps the host copy on the array: the row's
+            # materialize reads it again without a second transfer
+            return PendingClassify(
+                materialize, readback=lambda: np.asarray(plan["fused"])
+            )
 
         return [make_row(j) for j in range(k)]
 
@@ -1451,7 +1455,11 @@ class TpuClassifier:
                 results=results, xdp=xdp, stats_delta=stats_delta
             )
 
-        return PendingClassify(materialize)
+        # np.asarray keeps the host copy on the array: materialize reads
+        # it again without a second transfer
+        return PendingClassify(
+            materialize, readback=lambda: np.asarray(plan["fused"])
+        )
 
     def _note_payload_resident(self, plan, pay_hit, pay_rw,
                                row: Optional[int] = None) -> None:

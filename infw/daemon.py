@@ -37,6 +37,7 @@ import sys
 import threading
 import time
 from collections import deque
+from contextlib import nullcontext
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -386,6 +387,16 @@ class _FlowCounters:
         if not self._prefix:
             return vals
         return {f"{self._prefix}{k}": v for k, v in vals.items()}
+
+
+#: what a span site enters when tracing is off
+_NO_SPAN = nullcontext()
+
+
+def _span(tracer, name: str, seq: Optional[int] = None):
+    """The tracer's program span (SpanTracer.span), or a no-op when the
+    daemon runs without ``--trace``."""
+    return _NO_SPAN if tracer is None else tracer.span(name, seq)
 
 
 def _batch_from_wire(wire: np.ndarray, tcp_flags=None) -> PacketBatch:
@@ -1688,23 +1699,28 @@ class Daemon:
         def dispatch_one(chunk, trace) -> bool:
             try:
                 if packed:
-                    plan = clf.prepare_packed(
-                        chunk.wire, chunk.v4_only,
-                        tcp_flags=chunk.tcp_flags,
-                        payload=chunk.payload,
-                        payload_len=chunk.payload_len,
-                    )
+                    with _span(tracer, "infw.ring.h2d", chunk.seq):
+                        plan = clf.prepare_packed(
+                            chunk.wire, chunk.v4_only,
+                            tcp_flags=chunk.tcp_flags,
+                            payload=chunk.payload,
+                            payload_len=chunk.payload_len,
+                        )
                     if trace is not None:
                         trace.mark("h2d")
-                    pending = clf.classify_prepared(plan, apply_stats=True)
+                    with _span(tracer, "infw.ring.launch", chunk.seq):
+                        pending = clf.classify_prepared(
+                            plan, apply_stats=True
+                        )
                 else:
                     # non-packed backend (the cpu reference): rebuild
                     # the batch from the wire record — slower, but the
                     # ring must drain on every backend
-                    pending = clf.classify_async(
-                        _batch_from_wire(chunk.wire, chunk.tcp_flags),
-                        apply_stats=True,
-                    )
+                    with _span(tracer, "infw.ring.launch", chunk.seq):
+                        pending = clf.classify_async(
+                            _batch_from_wire(chunk.wire, chunk.tcp_flags),
+                            apply_stats=True,
+                        )
                 if trace is not None:
                     trace.mark("dispatch")
             except Exception as e:
@@ -1714,18 +1730,29 @@ class Daemon:
             inflight.append((chunk, pending, trace))
             return True
 
+        def pop():
+            # a pop that finds the ring empty has no seq
+            with _span(tracer, "infw.ring.pop") as span:
+                chunk = ring.pop(timeout=0.0)
+                if span is not None and chunk is not None:
+                    span.set_metadata(seq=int(chunk.seq))
+            return chunk
+
         while processed < budget:
             t0 = time.perf_counter()
-            chunk = carry.pop(0) if carry else ring.pop(timeout=0.0)
+            chunk = carry.pop(0) if carry else pop()
             if chunk is None:
                 break
             trace = None
             if tracer is not None:
                 # span taxonomy on the ring path: ingest = cursor pop,
                 # h2d = prepare_packed (staging device_put; the record
-                # arrives pre-packed so pack is the producer's cost),
-                # dispatch = program launch, materialize = readback,
-                # drain = slot release + bookkeeping
+                # arrives pre-packed so pack is the producer's cost; on
+                # the resident path it also launches the program),
+                # dispatch = classify_prepared, inflight = launched and
+                # queued behind the records ahead of it, materialize =
+                # readback + host finalize, drain = verdict sink + slot
+                # release
                 trace = tracer.begin(chunk.wire.shape[0])
                 trace.add("ingest", time.perf_counter() - t0)
             group = [chunk]
@@ -1737,7 +1764,7 @@ class Daemon:
                 # (releases stay in pop order either way).
                 while len(group) < super_k:
                     try:
-                        nxt = ring.pop(timeout=0.0)
+                        nxt = pop()
                     except ValueError as e:
                         log.error("ring ingest pop failed: %s", e)
                         break
@@ -1772,18 +1799,21 @@ class Daemon:
                     )
                 plan = None
                 try:
-                    plan = clf.prepare_packed_super(
-                        wire_stack, chunk.v4_only,
-                        tcp_flags_stack=flags_stack,
-                        payload_stack=pay_stack,
-                        payload_len_stack=plen_stack,
-                    )
+                    # the stacked launch's spans carry its first seq
+                    with _span(tracer, "infw.ring.h2d", chunk.seq):
+                        plan = clf.prepare_packed_super(
+                            wire_stack, chunk.v4_only,
+                            tcp_flags_stack=flags_stack,
+                            payload_stack=pay_stack,
+                            payload_len_stack=plen_stack,
+                        )
                     if plan is not None:
                         if trace is not None:
                             trace.mark("h2d")
-                        pends = clf.classify_prepared_super(
-                            plan, apply_stats=True
-                        )
+                        with _span(tracer, "infw.ring.launch", chunk.seq):
+                            pends = clf.classify_prepared_super(
+                                plan, apply_stats=True
+                            )
                         if trace is not None:
                             trace.mark("dispatch")
                 except Exception as e:
@@ -1814,19 +1844,35 @@ class Daemon:
 
     def _ring_drain_one(self) -> None:
         chunk, pending, trace = self._ring_inflight.popleft()
+        tracer = getattr(self, "tracer", None)
+        if trace is not None:
+            trace.mark("inflight")
+        out = None
         try:
-            out = pending.result()
+            if tracer is None:
+                out = pending.result()
+            else:
+                # readback = the wait on the device, finalize = the
+                # host's work on the outputs after it
+                with tracer.span("infw.ring.readback", chunk.seq):
+                    pending.wait()
+                with tracer.span("infw.ring.finalize", chunk.seq):
+                    out = pending.result()
             if trace is not None:
                 trace.mark("materialize")
-            if self.ring_verdict_sink is not None:
-                self.ring_verdict_sink(chunk.seq, out.results)
         except Exception as e:
             log.error("ring ingest classify failed: %s", e)
-        finally:
-            chunk.release()
-            if trace is not None:
-                trace.mark("drain")
-                self.tracer.finish(trace)  # trace only exists when tracer does
+        with _span(tracer, "infw.ring.sink", chunk.seq):
+            try:
+                if out is not None and self.ring_verdict_sink is not None:
+                    self.ring_verdict_sink(chunk.seq, out.results)
+            except Exception as e:
+                log.error("ring ingest classify failed: %s", e)
+            finally:
+                chunk.release()
+        if trace is not None:
+            trace.mark("drain")
+            tracer.finish(trace)  # trace only exists when tracer does
 
     def _maybe_prewarm_ladder(self, clf) -> None:
         """Pre-warm every batch-size ladder shape against the CURRENT
@@ -1908,50 +1954,60 @@ class Daemon:
         )
 
     def _file_loop(self) -> None:
-        while not self._stop.wait(self.file_poll_interval_s):
+        # one program span per phase of each turn (--trace)
+        tracer = getattr(self, "tracer", None)
+        while True:
+            with _span(tracer, "infw.loop.wait"):
+                stopped = self._stop.wait(self.file_poll_interval_s)
+            if stopped:
+                return
             # Scan and ingest are isolated from each other: a persistently
             # bad nodestate file must not starve packet classification.
-            try:
-                self.scan_nodestates_once()
-            except Exception as e:  # keep the loop alive
-                log.error("nodestate scan error: %s", e)
-            try:
-                self.scan_edits_once()
-                self._maybe_flush_edits()
-            except Exception as e:
-                log.error("edit scan error: %s", e)
-            try:
-                self.scan_tenant_edits_once()
-            except Exception as e:
-                log.error("tenant edit scan error: %s", e)
-            try:
-                self._tenant_dedup_maintenance()
-            except Exception as e:
-                log.error("tenant dedup sweep error: %s", e)
-            try:
-                self.process_ring_once()
-            except Exception as e:
-                log.error("ring ingest error: %s", e)
-            try:
-                self.process_ingest_once()
-            except Exception as e:
-                log.error("ingest error: %s", e)
-            try:
-                self._flow_maintenance()
-            except Exception as e:
-                log.error("flow maintenance error: %s", e)
-            try:
-                self._telemetry_maintenance()
-            except Exception as e:
-                log.error("telemetry maintenance error: %s", e)
-            try:
-                self._mlscore_maintenance()
-            except Exception as e:
-                log.error("mlscore maintenance error: %s", e)
-            try:
-                self._payload_maintenance()
-            except Exception as e:
-                log.error("payload maintenance error: %s", e)
+            with _span(tracer, "infw.loop.control"):
+                try:
+                    self.scan_nodestates_once()
+                except Exception as e:  # keep the loop alive
+                    log.error("nodestate scan error: %s", e)
+                try:
+                    self.scan_edits_once()
+                    self._maybe_flush_edits()
+                except Exception as e:
+                    log.error("edit scan error: %s", e)
+                try:
+                    self.scan_tenant_edits_once()
+                except Exception as e:
+                    log.error("tenant edit scan error: %s", e)
+                try:
+                    self._tenant_dedup_maintenance()
+                except Exception as e:
+                    log.error("tenant dedup sweep error: %s", e)
+            with _span(tracer, "infw.loop.ring"):
+                try:
+                    self.process_ring_once()
+                except Exception as e:
+                    log.error("ring ingest error: %s", e)
+            with _span(tracer, "infw.loop.ingest"):
+                try:
+                    self.process_ingest_once()
+                except Exception as e:
+                    log.error("ingest error: %s", e)
+            with _span(tracer, "infw.loop.maintenance"):
+                try:
+                    self._flow_maintenance()
+                except Exception as e:
+                    log.error("flow maintenance error: %s", e)
+                try:
+                    self._telemetry_maintenance()
+                except Exception as e:
+                    log.error("telemetry maintenance error: %s", e)
+                try:
+                    self._mlscore_maintenance()
+                except Exception as e:
+                    log.error("mlscore maintenance error: %s", e)
+                try:
+                    self._payload_maintenance()
+                except Exception as e:
+                    log.error("payload maintenance error: %s", e)
 
     def _attach_flow_events(self, clf) -> None:
         """Wire a classifier's flow tier to the obs event ring (once
@@ -2405,10 +2461,13 @@ def main(argv: Optional[List[str]] = None) -> int:
         default=os.environ.get("INFW_TRACE", "")
         not in ("", "0", "false", "no"),
         help="serving-path tracing: per-stage span clocks (ingest -> "
-             "pack -> H2D -> dispatch -> materialize -> drain) exported "
-             "as Prometheus histograms on /metrics, with sampled "
-             "TraceSpanRecords for slow admissions on the obs event "
-             "ring.  CLI beats INFW_TRACE",
+             "pack -> H2D -> dispatch -> inflight -> materialize -> "
+             "drain) exported as Prometheus histograms on /metrics, with "
+             "sampled TraceSpanRecords for slow admissions on the obs "
+             "event ring; and program spans (infw.loop.* per loop phase, "
+             "infw.ring.* per ring record with its seq) that an "
+             "in-process jax.profiler trace of the daemon records on the "
+             "device ops' clock.  CLI beats INFW_TRACE",
     )
     p.add_argument(
         "--trace-slow-us", type=float,

@@ -25,6 +25,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import os
+import re
 import threading
 import time
 from typing import NamedTuple, Optional, Tuple
@@ -1026,12 +1027,14 @@ def ctrie_walk_rows(
 
 
 def _ctrie_result_and_score(cdev: CTrieTables, batch: DeviceBatch, d_max: int):
-    rows = ctrie_walk_rows(cdev, batch, d_max)
-    matched = (
-        rows[:, 0].astype(jnp.int32) | (rows[:, 1].astype(jnp.int32) << 16)
-    ) > 0
-    score = jnp.where(matched, rows[:, 2].astype(jnp.int32) + 1, 0)
-    return rule_scan(joined_rule_rows(rows), batch), score
+    with jax.named_scope("lpm"):
+        rows = ctrie_walk_rows(cdev, batch, d_max)
+        matched = (
+            rows[:, 0].astype(jnp.int32) | (rows[:, 1].astype(jnp.int32) << 16)
+        ) > 0
+        score = jnp.where(matched, rows[:, 2].astype(jnp.int32) + 1, 0)
+    with jax.named_scope("rule_scan"):
+        return rule_scan(joined_rule_rows(rows), batch), score
 
 
 def classify_ctrie(
@@ -1040,7 +1043,8 @@ def classify_ctrie(
     """Full forward pass via the compressed walk; verdict-identical to
     classify(use_trie=True) on the same tables."""
     raw, _score = _ctrie_result_and_score(cdev, batch, d_max)
-    return finalize(raw, batch)
+    with jax.named_scope("rule_scan"):
+        return finalize(raw, batch)
 
 
 def classify_ctrie_with_overlay(
@@ -1054,8 +1058,9 @@ def classify_ctrie_with_overlay(
     side-table (same longest-prefix combine as classify_with_overlay)."""
     raw_m, score_m = _ctrie_result_and_score(cdev, batch, d_max)
     raw_o, score_o = _raw_result_and_score(overlay, batch, use_trie=False)
-    result = jnp.where(score_o > score_m, raw_o, raw_m)
-    return finalize(result, batch)
+    with jax.named_scope("rule_scan"):
+        result = jnp.where(score_o > score_m, raw_o, raw_m)
+        return finalize(result, batch)
 
 
 @functools.lru_cache(maxsize=None)
@@ -2856,44 +2861,53 @@ def _raw_result_and_score(
     matching one packet implies the same masked prefix, and identities
     are deduplicated at compile/routing time)."""
     if use_trie and tables.joined.shape[0] > 1:
-        rows = trie_walk_joined(
-            tables.trie_levels, tables.joined, tables.root_lut, batch
-        )
-        if rows.dtype == jnp.uint16:
-            matched = (rows[:, 0].astype(jnp.int32)
-                       | (rows[:, 1].astype(jnp.int32) << 16)) > 0
-            ml = rows[:, 2].astype(jnp.int32)
+        with jax.named_scope("lpm"):
+            rows = trie_walk_joined(
+                tables.trie_levels, tables.joined, tables.root_lut, batch
+            )
+            if rows.dtype == jnp.uint16:
+                matched = (rows[:, 0].astype(jnp.int32)
+                           | (rows[:, 1].astype(jnp.int32) << 16)) > 0
+                ml = rows[:, 2].astype(jnp.int32)
+            else:
+                matched = rows[:, 0] > 0
+                ml = rows[:, 1]
+            score = jnp.where(matched, ml + 1, 0)
+        with jax.named_scope("rule_scan"):
+            return rule_scan(joined_rule_rows(rows), batch), score
+    with jax.named_scope("lpm"):
+        if use_trie:
+            tidx = lpm_trie(tables, batch)
         else:
-            matched = rows[:, 0] > 0
-            ml = rows[:, 1]
-        score = jnp.where(matched, ml + 1, 0)
-        return rule_scan(joined_rule_rows(rows), batch), score
-    if use_trie:
-        tidx = lpm_trie(tables, batch)
-    else:
-        tidx = lpm_dense(tables, batch)
-    ml = jnp.take(tables.mask_len, jnp.clip(tidx, 0), mode="clip")
-    score = jnp.where(tidx >= 0, ml + 1, 0)
-    return rule_scan(gather_rule_rows(tables.rules, tidx), batch), score
+            tidx = lpm_dense(tables, batch)
+        ml = jnp.take(tables.mask_len, jnp.clip(tidx, 0), mode="clip")
+        score = jnp.where(tidx >= 0, ml + 1, 0)
+    with jax.named_scope("rule_scan"):
+        return rule_scan(gather_rule_rows(tables.rules, tidx), batch), score
 
 
 def classify(
     tables: DeviceTables, batch: DeviceBatch, *, use_trie: bool
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Full forward pass: LPM -> gather rules -> scan -> finalize."""
+    """Full forward pass: LPM -> gather rules -> scan -> finalize (the
+    ``lpm`` and ``rule_scan`` stages of RESIDENT_STAGES)."""
     if use_trie and tables.joined.shape[0] > 1:
         # one-gather tail: the walk's win position returns the rules row
-        rows = trie_walk_joined(
-            tables.trie_levels, tables.joined, tables.root_lut, batch
-        )
-        result = rule_scan(joined_rule_rows(rows), batch)
+        with jax.named_scope("lpm"):
+            rows = trie_walk_joined(
+                tables.trie_levels, tables.joined, tables.root_lut, batch
+            )
+        with jax.named_scope("rule_scan"):
+            result = rule_scan(joined_rule_rows(rows), batch)
+            return finalize(result, batch)
+    with jax.named_scope("lpm"):
+        if use_trie:
+            tidx = lpm_trie(tables, batch)
+        else:
+            tidx = lpm_dense(tables, batch)
+    with jax.named_scope("rule_scan"):
+        result = rule_scan(gather_rule_rows(tables.rules, tidx), batch)
         return finalize(result, batch)
-    if use_trie:
-        tidx = lpm_trie(tables, batch)
-    else:
-        tidx = lpm_dense(tables, batch)
-    result = rule_scan(gather_rule_rows(tables.rules, tidx), batch)
-    return finalize(result, batch)
 
 
 def classify_with_overlay(
@@ -2913,8 +2927,9 @@ def classify_with_overlay(
     greater-than gives the overlay exactly kernel-LPM semantics."""
     raw_m, score_m = _raw_result_and_score(tables, batch, use_trie=use_trie)
     raw_o, score_o = _raw_result_and_score(overlay, batch, use_trie=False)
-    result = jnp.where(score_o > score_m, raw_o, raw_m)
-    return finalize(result, batch)
+    with jax.named_scope("rule_scan"):
+        result = jnp.where(score_o > score_m, raw_o, raw_m)
+        return finalize(result, batch)
 
 
 @functools.lru_cache(maxsize=None)
@@ -6245,12 +6260,14 @@ def _resident_step_core(
     depth: Optional[int], d_max: int, sketch=None, score=None,
     payload=None,
 ):
-    batch = unpack_wire(wire)
-    e1 = (epoch + jnp.int32(1)).astype(jnp.int32)
-    served, hit, stale, flow1 = _flow_probe_parts(
-        flow, gens, page_table, batch, tenant, tflags, e1, max_age,
-        slab_entries=slab_entries, ways=ways,
-    )
+    with jax.named_scope("wire_decode"):
+        batch = unpack_wire(wire)
+    with jax.named_scope("flow_probe"):
+        e1 = (epoch + jnp.int32(1)).astype(jnp.int32)
+        served, hit, stale, flow1 = _flow_probe_parts(
+            flow, gens, page_table, batch, tenant, tflags, e1, max_age,
+            slab_entries=slab_entries, ways=ways,
+        )
     # stateless classify of EVERY lane against the SAME table snapshot:
     # the hit lanes' stateless results are discarded by the merge below
     # (at the small-batch rungs the extra lanes are far cheaper than a
@@ -6278,7 +6295,8 @@ def _resident_step_core(
             res, _x, _s = classify(t, batch, use_trie=use_trie)
     # the wire contract (check_wire_ruleids at plan time) guarantees the
     # stateless result fits 16 bits, exactly like the fused wire path
-    merged = jnp.where(hit, served, res & 0xFFFF).astype(jnp.uint32)
+    with jax.named_scope("merge"):
+        merged = jnp.where(hit, served, res & 0xFFFF).astype(jnp.uint32)
     sc2 = score_out = anom = None
     if score is not None:
         # MXU anomaly scoring (ISSUE-14): the feature update + forest/
@@ -6291,10 +6309,11 @@ def _resident_step_core(
         # a rule patch uses.
         from . import mxu_score as mxu_score_mod
 
-        sc2, score_out, anom, merged2 = mxu_score_mod._score_update_core(
-            sc, batch, tenant, tflags, merged, model, tparams, spec=score,
-        )
-        merged2 = merged2.astype(jnp.uint32)
+        with jax.named_scope("score"):
+            sc2, score_out, anom, merged2 = mxu_score_mod._score_update_core(
+                sc, batch, tenant, tflags, merged, model, tparams, spec=score,
+            )
+            merged2 = merged2.astype(jnp.uint32)
     else:
         merged2 = merged
     pay_hit = pay_rw = None
@@ -6311,52 +6330,55 @@ def _resident_step_core(
         # precedes them in the operand order.
         from . import acmatch as acmatch_mod
 
-        bitmap = acmatch_mod._acmatch_core(
-            ptrans, pmatch, pay, plen, spec=payload
-        )
-        merged3, pay_hit, pay_rw = acmatch_mod._payload_merge_core(
-            merged2, bitmap, pmode, batch.proto, batch.dst_port
-        )
-        merged3 = merged3.astype(jnp.uint32)
+        with jax.named_scope("payload"):
+            bitmap = acmatch_mod._acmatch_core(
+                ptrans, pmatch, pay, plen, spec=payload
+            )
+            merged3, pay_hit, pay_rw = acmatch_mod._payload_merge_core(
+                merged2, bitmap, pmode, batch.proto, batch.dst_port
+            )
+            merged3 = merged3.astype(jnp.uint32)
     else:
         merged3 = merged2
-    flow2, counts = _flow_insert_core(
-        flow1, gens, page_table, batch, tenant, tflags, merged3, e1,
-        slab_entries=slab_entries, ways=ways, lane_ok=~hit,
-    )
+    with jax.named_scope("flow_insert"):
+        flow2, counts = _flow_insert_core(
+            flow1, gens, page_table, batch, tenant, tflags, merged3, e1,
+            slab_entries=slab_entries, ways=ways, lane_ok=~hit,
+        )
     # res16-only readback (the wire8 contract): per-ruleId statistics
     # derive HOST-side from the merged verdicts + the pkt_len column
     # that never left the host — shipping the (1024, 6) stats tensor
     # would cost ~24 KB per admission, dwarfing the ~100 B the resident
     # loop actually needs back
-    parts = [
-        _pack_res16(merged3.astype(jnp.uint16)),
-        _pack_bits32(hit),
-        jnp.stack([
-            jnp.sum(hit.astype(jnp.int32)),
-            jnp.sum(stale.astype(jnp.int32)),
-        ]),
-        counts,
-    ]
-    if score is not None:
-        # scoring extension of the fused readback: the anomaly bitmap
-        # (b/32 words) and the int16-saturated per-lane scores (b/2
-        # words) — what shadow records, the precision/recall legs and
-        # the cross-path identity gate read; internal state stays exact
-        # int32 on device
-        s16 = jnp.clip(score_out, -32768, 32767).astype(jnp.int16)
-        parts.append(_pack_bits32(anom))
-        parts.append(_pack_res16(s16.astype(jnp.uint16)))
-    if payload is not None:
-        # payload extension of the fused readback: the matched-lane and
-        # rewritten-lane bitmaps (b/32 words each) — the counters and
-        # the classic-path identity gate read these; the FULL (b, PW)
-        # match bitmap never crosses the link on the resident path (the
-        # standalone jitted_acmatch launch serves statecheck's
-        # bit-identity compare instead)
-        parts.append(_pack_bits32(pay_hit))
-        parts.append(_pack_bits32(pay_rw))
-    fused = jnp.concatenate(parts)
+    with jax.named_scope("pack_out"):
+        parts = [
+            _pack_res16(merged3.astype(jnp.uint16)),
+            _pack_bits32(hit),
+            jnp.stack([
+                jnp.sum(hit.astype(jnp.int32)),
+                jnp.sum(stale.astype(jnp.int32)),
+            ]),
+            counts,
+        ]
+        if score is not None:
+            # scoring extension of the fused readback: the anomaly
+            # bitmap (b/32 words) and the int16-saturated per-lane
+            # scores (b/2 words) — what shadow records, the precision/
+            # recall legs and the cross-path identity gate read;
+            # internal state stays exact int32 on device
+            s16 = jnp.clip(score_out, -32768, 32767).astype(jnp.int16)
+            parts.append(_pack_bits32(anom))
+            parts.append(_pack_res16(s16.astype(jnp.uint16)))
+        if payload is not None:
+            # payload extension of the fused readback: the matched-lane
+            # and rewritten-lane bitmaps (b/32 words each) — the
+            # counters and the classic-path identity gate read these;
+            # the FULL (b, PW) match bitmap never crosses the link on
+            # the resident path (the standalone jitted_acmatch launch
+            # serves statecheck's bit-identity compare instead)
+            parts.append(_pack_bits32(pay_hit))
+            parts.append(_pack_bits32(pay_rw))
+        fused = jnp.concatenate(parts)
     if sketch is not None:
         # device-resident telemetry (ISSUE-13): the sketch update rides
         # the SAME device program as the verdicts — count-min + top-K +
@@ -6366,15 +6388,118 @@ def _resident_step_core(
         # is the only D2H the telemetry plane ever pays)
         from . import sketch as sketch_mod
 
-        sk2 = sketch_mod._sketch_update_core(
-            sk, batch, tenant, tflags, merged3, spec=sketch,
-        )
+        with jax.named_scope("sketch"):
+            sk2 = sketch_mod._sketch_update_core(
+                sk, batch, tenant, tflags, merged3, spec=sketch,
+            )
         if score is not None:
             return flow2, e1, sk2, sc2, fused
         return flow2, e1, sk2, fused
     if score is not None:
         return flow2, e1, sc2, fused
     return flow2, e1, fused
+
+
+#: the resident step's stages: each is a jax.named_scope of
+#: _resident_step_core (lpm and rule_scan inside classify and its
+#: twins), so each device op of the compiled step carries one in its
+#: op_name metadata; score, payload and sketch exist only where those
+#: tiers ride the step
+RESIDENT_STAGES = (
+    "wire_decode", "flow_probe", "lpm", "rule_scan", "merge", "score",
+    "payload", "flow_insert", "pack_out", "sketch",
+)
+
+#: HLO module text: a computation's header, one of its instructions (and
+#: the name it defines), a name it refers to, the computation a fusion
+#: calls, and the op_name of an instruction's metadata
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="((?:[^"\\]|\\.)*)"')
+
+
+def _op_name_stage(op_name: str) -> Optional[str]:
+    for part in reversed(op_name.split("/")):
+        if part in RESIDENT_STAGES:
+            return part
+    return None
+
+
+def hlo_stage_map(hlo_text: str) -> dict:
+    """{instruction name: stage} for every instruction of an optimized
+    HLO module's text (``compiled.as_text()``, or a module the profiler
+    recorded).  Instruction names are the op names a device trace shows,
+    so this ties device time to stages.
+
+    An instruction's stage is the innermost scope of its op_name that
+    names one of RESIDENT_STAGES.  The compiler makes some instructions
+    with no metadata (layout copies, the fusions of some rewrites): a
+    fusion takes its fused computation's stage (its root's, else the
+    most common one inside); any other takes the stage of the first of
+    its users that has one, else of the first of its operands.  An
+    instruction left with none maps to ``"other"``."""
+    own, comp_of, refs, calls, roots, order = {}, {}, {}, {}, {}, []
+    comps = set()
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            h = _HLO_COMPUTATION.match(line)
+            if h is not None:
+                comp = h.group(1)
+                comps.add(comp)
+            continue
+        name, rest = m.group(2), line[m.end():]
+        order.append(name)
+        comp_of[name] = comp
+        if m.group(1):
+            roots[comp] = name
+        op = _HLO_OP_NAME.search(rest)
+        own[name] = _op_name_stage(op.group(1)) if op else None
+        c = _HLO_CALLS.search(rest)
+        calls[name] = c.group(1) if c else None
+        refs[name] = _HLO_REF.findall(rest.split(", metadata=")[0])
+    stage = dict(own)
+    members: dict = {}
+    for name in order:
+        members.setdefault(comp_of[name], []).append(name)
+    for name in order:
+        callee = calls[name]
+        if stage[name] is None and callee in members:
+            inner = [own[i] for i in members[callee] if own[i]]
+            stage[name] = own.get(roots.get(callee)) or (
+                max(set(inner), key=inner.count) if inner else None)
+    operands = {n: [r for r in refs[n] if r in own and r not in comps]
+                for n in order}
+    users: dict = {}
+    caller: dict = {}  # called computation -> an instruction that calls it
+    for name in order:
+        for r in operands[name]:
+            users.setdefault(r, []).append(name)
+        for r in refs[name]:
+            if r in comps:
+                caller.setdefault(r, name)
+    # text order is a topological order: users resolve back to front,
+    # operands front to back; what is left inside a called computation
+    # (a fusion's body, a reducer) takes its caller's stage
+    for _ in range(len(comps) + 1):
+        for name in reversed(order):
+            if stage[name] is None:
+                stage[name] = next(
+                    (stage[u] for u in users.get(name, ()) if stage[u]), None)
+        for name in order:
+            if stage[name] is None:
+                stage[name] = next(
+                    (stage[o] for o in operands[name] if stage[o]), None)
+        left = [n for n in order if stage[n] is None
+                and stage.get(caller.get(comp_of[n]))]
+        if not left:
+            break
+        for name in left:
+            stage[name] = stage[caller[comp_of[name]]]
+    return {n: stage[n] or "other" for n in order}
 
 
 def split_resident_outputs(arr: np.ndarray, b: int):
@@ -6496,7 +6621,7 @@ def jitted_resident_step(
     has_sc = score is not None
     has_pay = payload is not None
 
-    def f(*args):
+    def resident_step(*args):
         flow, gens, page_table, epoch = args[:4]
         i = 4
         sk = sc = model = tparams = None
@@ -6528,8 +6653,9 @@ def jitted_resident_step(
             pmode=pmode, **kw,
         )
 
-    return jax.jit(f, donate_argnums=resident_donate_argnums(has_sk,
-                                                             has_sc))
+    # the function's name is the compiled module's: jit_resident_step
+    return jax.jit(resident_step,
+                   donate_argnums=resident_donate_argnums(has_sk, has_sc))
 
 
 # === resident superbatch: the device-side epoch loop (ISSUE-16) ==============
@@ -6593,7 +6719,7 @@ def jitted_resident_superbatch(
     has_sc = score is not None
     has_pay = payload is not None
 
-    def f(*args):
+    def resident_superbatch(*args):
         flow, gens, page_table, epoch = args[:4]
         i = 4
         sk = sc = model = tparams = None
@@ -6655,5 +6781,5 @@ def jitted_resident_superbatch(
         outs.append(fused)
         return tuple(outs)
 
-    return jax.jit(f, donate_argnums=resident_donate_argnums(has_sk,
-                                                             has_sc))
+    return jax.jit(resident_superbatch,
+                   donate_argnums=resident_donate_argnums(has_sk, has_sc))

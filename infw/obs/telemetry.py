@@ -542,9 +542,11 @@ class TelemetryTier:
 
 #: the span taxonomy, in serving order: ingest (ring pop / file read
 #: wait), pack (parse + wire pack + encode), h2d (staging device_put),
-#: dispatch (program launch), materialize (readback + host finalize),
-#: drain (event/stat emission)
-SPAN_STAGES = ("ingest", "pack", "h2d", "dispatch", "materialize", "drain")
+#: dispatch (program launch), inflight (launched, waiting for the drain
+#: to reach it behind the records ahead of it; the ring path),
+#: materialize (readback + host finalize), drain (event/stat emission)
+SPAN_STAGES = ("ingest", "pack", "h2d", "dispatch", "inflight",
+               "materialize", "drain")
 
 #: log2 bucket upper bounds in microseconds: 1us .. ~1.05s, +Inf
 SPAN_BUCKETS_US = tuple(float(1 << i) for i in range(21))
@@ -644,11 +646,15 @@ class AdmissionTrace:
 
 class SpanTracer:
     """End-to-end serving-path tracer: histograms for the population,
-    token-bucket-sampled TraceSpanRecords for slow admissions."""
+    token-bucket-sampled TraceSpanRecords for slow admissions, and
+    program spans on the profiler's clock (``span``)."""
 
     def __init__(self, ring=None, histograms: Optional[SpanHistograms] = None,
                  slow_us: float = 50_000.0, sample_rate: float = 4.0,
                  sample_burst: float = 16.0) -> None:
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.histograms = histograms or SpanHistograms()
         self._ring = ring
         self.slow_us = float(slow_us)
@@ -663,6 +669,18 @@ class SpanTracer:
 
     def begin(self, n_packets: int = 0) -> AdmissionTrace:
         return AdmissionTrace(n_packets)
+
+    def span(self, name: str, seq: Optional[int] = None):
+        """A context manager that records ``name`` as a span of an
+        in-process ``jax.profiler`` trace, on the clock of the device
+        ops, with the ring record's ``seq`` as its identifier; nothing is
+        recorded while no trace is being taken.  The profiler keeps the
+        spans in memory and writes them when the trace stops.  ``seq``
+        can be set once known: ``with tracer.span(n) as s:
+        s.set_metadata(seq=...)``."""
+        if seq is None:
+            return self._annotation(name)
+        return self._annotation(name, seq=int(seq))
 
     def finish(self, trace: AdmissionTrace,
                now: Optional[float] = None) -> None:

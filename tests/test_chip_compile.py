@@ -14,6 +14,7 @@ around the compiles, since an entry written for a described chip cannot
 be read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,7 +112,8 @@ def test_arena_walk_kernel(one_chip):
 
 def test_resident_step(one_chip):
     """The resident fused admission program at batch rung 1024, with its
-    flow columns and epoch donated."""
+    flow columns and epoch donated: a module named after the step, every
+    instruction that does work in one of its named stages."""
     cfg, flow, gens, pages, epoch, max_age, zeros = (
         kernels._resident_operands(1024)
     )
@@ -120,7 +122,14 @@ def test_resident_step(one_chip):
     )
     args = (flow, gens, pages, epoch, kernels._fixture_device_tables(True),
             kernels._fixture_wire(1024), zeros, zeros, max_age)
-    compile_for(fn, *shapes(args, one_chip))
+    text = compile_for(fn, *shapes(args, one_chip)).as_text()
+    assert text.startswith("HloModule jit_resident_step,")
+    stages = jaxpath.hlo_stage_map(text)
+    structural = ("parameter", "constant", "tuple", "get-tuple-element")
+    opcode = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+.*?\s([a-z][\w\-]*)\(")
+    for m in filter(None, map(opcode.match, text.splitlines())):
+        if m.group(2) not in structural:
+            assert stages[m.group(1)] != "other", m.group(0)[:200]
 
 
 def test_mesh_walk_on_four_chips(topo, cache_off):
