@@ -148,7 +148,7 @@ class MeshTpuClassifier(TpuClassifier):
 
     def _make_flow_tier(self, cfg, track_model: bool = False):
         """Place the flow-tier columns by the declared partition rules
-        (parallel.mesh.FLOW_PARTITION_RULES): flow rows shard over
+        (parallel.mesh.FLOW_PARTITION_RULES): flow slots shard over
         "rules" when the capacity divides the axis, the steering state
         replicates, and the probe/insert dispatches run under the same
         jitted factories as the single chip — GSPMD, no mesh-specific

@@ -473,11 +473,12 @@ class FlowTier:
         #: FlowEvictRecord on the obs ring)
         self.on_evict: Optional[Callable] = None
         C = config.capacity
+        # slot-minor on the device (jaxpath.FlowTable)
         host = {
-            "keys": np.zeros((C, FLOW_KEY_WORDS), np.uint32),
-            "vg": np.zeros((C, 2), np.int32),
-            "se": np.zeros((C, 2), np.int32),
-            "cnt": np.zeros((C, 3), np.int32),
+            "keys": np.zeros((FLOW_KEY_WORDS, C), np.uint32),
+            "vg": np.zeros((2, C), np.int32),
+            "se": np.zeros((2, C), np.int32),
+            "cnt": np.zeros((3, C), np.int32),
         }
         put = lambda name, a: jax.device_put(
             jnp.asarray(a), self._shardings.get(name, device)
@@ -1053,14 +1054,16 @@ class FlowTier:
             return self._epoch
 
     def flow_columns(self) -> Dict[str, np.ndarray]:
-        """Host copies of the device columns (the model-checker compare
+        """Host copies of the device columns in the (C, k) slot-major
+        form HostFlowModel.columns() keeps (the model-checker compare
         side).  Materialized INSIDE the lock: the resident loop donates
         these buffers per admission, so an off-lock snapshot could be
         deleted by a concurrent dispatch mid-read."""
         with self._lock:
             flow = self._flow
             return {
-                k: np.asarray(getattr(flow, k)) for k in flow._fields
+                k: np.ascontiguousarray(np.asarray(getattr(flow, k)).T)
+                for k in flow._fields
             }
 
     def counter_values(self) -> Dict[str, int]:

@@ -608,10 +608,10 @@ def _resident_operands(b: int):
     cfg = FlowConfig.make(entries=512)
     C = cfg.capacity
     flow = jaxpath.FlowTable(
-        keys=jax.device_put(np.zeros((C, 8), np.uint32)),
-        vg=jax.device_put(np.zeros((C, 2), np.int32)),
-        se=jax.device_put(np.zeros((C, 2), np.int32)),
-        cnt=jax.device_put(np.zeros((C, 3), np.int32)),
+        keys=jax.device_put(np.zeros((8, C), np.uint32)),
+        vg=jax.device_put(np.zeros((2, C), np.int32)),
+        se=jax.device_put(np.zeros((2, C), np.int32)),
+        cnt=jax.device_put(np.zeros((3, C), np.int32)),
     )
     gens = jax.device_put(np.zeros(1, np.int32))
     pages = jax.device_put(np.zeros(1, np.int32))

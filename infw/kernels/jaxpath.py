@@ -5858,20 +5858,25 @@ FLOW_KEY_WORDS = 8
 
 
 class FlowTable(NamedTuple):
-    """Device-resident flow columns (C = pages * slab_entries rows).
-    Mutable state is packed into THREE narrow matrices so the probe's
-    in-kernel updates are 3 scatter ops, not 6 — scatter op count is
-    what the probe's cost scales with."""
+    """Device-resident flow columns (C = pages * slab_entries slots),
+    stored slot-minor, (k, C): the probe's way-gather along the slot
+    axis yields (k, W, B) planes with the batch on the minor axis, the
+    layout the way compares and reductions take.  Mutable state is packed
+    into THREE narrow matrices so the probe's in-kernel updates are 3
+    scatter ops, not 6 — scatter op count is what the probe's cost
+    scales with.  The host side (FlowTier.flow_columns, HostFlowModel)
+    keeps the (C, k) slot-major form."""
 
-    keys: jax.Array  # (C, 8) uint32 [tenant, ifindex, ip0..3, m0, m1]
-    vg: jax.Array    # (C, 2) int32 [cached res16 verdict, tenant gen]
-    se: jax.Array    # (C, 2) int32 [FLOW_* state, last-seen epoch]
-    cnt: jax.Array   # (C, 3) int32 [pkts, sum(len>>8), sum(len&0xFF)]
+    keys: jax.Array  # (8, C) uint32 [tenant, ifindex, ip0..3, m0, m1]
+    vg: jax.Array    # (2, C) int32 [cached res16 verdict, tenant gen]
+    se: jax.Array    # (2, C) int32 [FLOW_* state, last-seen epoch]
+    cnt: jax.Array   # (3, C) int32 [pkts, sum(len>>8), sum(len&0xFF)]
 
 
 def flow_key_words(batch: DeviceBatch, tenant: jax.Array) -> jax.Array:
-    """(B, 8) uint32 exact-match key covering every field the verdict
-    depends on (pkt_len only feeds statistics, never the verdict)."""
+    """(8, B) uint32 exact-match key planes covering every field the
+    verdict depends on (pkt_len only feeds statistics, never the
+    verdict)."""
     m0 = (
         (batch.proto.astype(jnp.uint32) & 0xFF)
         | ((batch.dst_port.astype(jnp.uint32) & 0xFFFF) << 8)
@@ -5891,33 +5896,33 @@ def flow_key_words(batch: DeviceBatch, tenant: jax.Array) -> jax.Array:
             batch.ip_words[:, 3].astype(jnp.uint32),
             m0,
             m1,
-        ],
-        axis=1,
+        ]
     )
 
 
 def _flow_hash(keys: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """FNV-1a over the 8 key words -> (h1, h2) uint32; h2 is forced odd
+    """FNV-1a over the 8 key planes -> (h1, h2) uint32; h2 is forced odd
     so the double-hash probe sequence visits distinct slots in a pow2
     slab.  Pure wrapping u32 arithmetic — the numpy model computes the
     identical values."""
-    h = jnp.full(keys.shape[:1], 0x811C9DC5, jnp.uint32)
+    h = jnp.full(keys.shape[1:], 0x811C9DC5, jnp.uint32)
     for w in range(FLOW_KEY_WORDS):
-        h = (h ^ keys[:, w].astype(jnp.uint32)) * jnp.uint32(0x01000193)
+        h = (h ^ keys[w]) * jnp.uint32(0x01000193)
     return h, (h >> 16) | jnp.uint32(1)
 
 
 def _flow_slots(
     keys: jax.Array, page: jax.Array, *, slab_entries: int, ways: int
 ) -> jax.Array:
-    """(B, W) int32 global candidate slot ids (page-slab-local double
+    """(W, B) int32 global candidate slot ids (page-slab-local double
     hashing); ``slab_entries`` must be a power of two."""
     h1, h2 = _flow_hash(keys)
-    w = jnp.arange(ways, dtype=jnp.uint32)[None, :]
-    local = (h1[:, None] + w * h2[:, None]) & jnp.uint32(slab_entries - 1)
-    return (
-        jnp.clip(page, 0)[:, None] * slab_entries + local.astype(jnp.int32)
-    )
+    base = jnp.clip(page, 0) * slab_entries
+    mask = jnp.uint32(slab_entries - 1)
+    return jnp.stack([
+        base + ((h1 + jnp.uint32(w) * h2) & mask).astype(jnp.int32)
+        for w in range(ways)
+    ])
 
 
 def _pack_bits32(mask: jax.Array) -> jax.Array:
@@ -5961,42 +5966,39 @@ def _flow_probe_parts(
     Per-ruleId statistics for the served lanes derive HOST-side from the
     returned res16 + pkt_len (the wire8 readback contract), so the probe
     ships no stats tensor."""
-    C = flow.se.shape[0]
+    C = flow.se.shape[1]
     page = _arena_pages(page_table, tenant)
     keyw = flow_key_words(batch, tenant)
     is_ip = (batch.kind == KIND_IPV4) | (batch.kind == KIND_IPV6)
     elig = is_ip & (batch.l4_ok != 0) & (page >= 0)
     cand = _flow_slots(keyw, page, slab_entries=slab_entries, ways=ways)
-    ek = jnp.take(flow.keys, cand, axis=0, mode="clip")     # (B, W, 8)
-    ese = jnp.take(flow.se, cand, axis=0, mode="clip")      # (B, W, 2)
-    evg = jnp.take(flow.vg, cand, axis=0, mode="clip")
-    match = jnp.all(ek == keyw[:, None, :], axis=2) & elig[:, None]
-    live = ese[:, :, 0] >= FLOW_EST
+    ek = jnp.take(flow.keys, cand, axis=1, mode="clip")   # (8, W, B)
+    ese = jnp.take(flow.se, cand, axis=1, mode="clip")    # (2, W, B)
+    evg = jnp.take(flow.vg, cand, axis=1, mode="clip")
+    match = jnp.all(ek == keyw[:, None, :], axis=0) & elig[None, :]
+    live = ese[0] >= FLOW_EST
     mygen = jnp.take(gens, jnp.clip(tenant, 0, gens.shape[0] - 1),
                      mode="clip")
-    gen_ok = evg[:, :, 1] == mygen[:, None]
-    fresh = (epoch_now - ese[:, :, 1]) <= max_age
+    gen_ok = evg[1] == mygen[None, :]
+    fresh = (epoch_now - ese[1]) <= max_age
     hit_w = match & live & gen_ok & fresh
     stale_w = match & live & fresh & ~gen_ok
     W = ways
-    widx = jnp.arange(W, dtype=jnp.int32)[None, :]
-    first = jnp.min(jnp.where(hit_w, widx, W), axis=1)
+    widx = jnp.arange(W, dtype=jnp.int32)[:, None]
+    first = jnp.min(jnp.where(hit_w, widx, W), axis=0)
     hit = first < W
-    sel = jnp.sum(jnp.where(widx == first[:, None], cand, 0), axis=1)
-    stale = jnp.any(stale_w, axis=1) & ~hit
+    at_first = widx == first[None, :]
+    sel = jnp.sum(jnp.where(at_first, cand, 0), axis=0)
+    stale = jnp.any(stale_w, axis=0) & ~hit
     slot = jnp.where(hit, sel, C)  # C = dropped by scatter mode="drop"
 
     served = jnp.where(
-        hit,
-        jnp.sum(jnp.where(widx == first[:, None], evg[:, :, 0], 0), axis=1),
-        0,
+        hit, jnp.sum(jnp.where(at_first, evg[0], 0), axis=0), 0
     ).astype(jnp.uint32)
 
     ln = batch.pkt_len
-    cnt = flow.cnt.at[slot].add(
-        jnp.stack(
-            [jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF], axis=1
-        ),
+    cnt = flow.cnt.at[:, slot].add(
+        jnp.stack([jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF]),
         mode="drop",
     )
     is_tcp = batch.proto == IPPROTO_TCP
@@ -6006,21 +6008,15 @@ def _flow_probe_parts(
     # last-seen refresh (epoch_now >= any stored epoch by monotonicity);
     # one min-scatter applies RST teardown
     big = jnp.int32(np.iinfo(np.int32).max)
-    se = flow.se.at[slot].max(
-        jnp.stack(
-            [
-                jnp.where(hit & fin, FLOW_FIN, -1).astype(jnp.int32),
-                jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32),
-            ],
-            axis=1,
-        ),
+    se = flow.se.at[:, slot].max(
+        jnp.stack([
+            jnp.where(hit & fin, FLOW_FIN, -1).astype(jnp.int32),
+            jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32),
+        ]),
         mode="drop",
     )
-    se = se.at[jnp.where(hit & rst, slot, C)].min(
-        jnp.stack(
-            [jnp.full_like(slot, FLOW_EMPTY), jnp.full_like(slot, big)],
-            axis=1,
-        ),
+    se = se.at[:, jnp.where(hit & rst, slot, C)].min(
+        jnp.stack([jnp.full_like(slot, FLOW_EMPTY), jnp.full_like(slot, big)]),
         mode="drop",
     )
     return served, hit, stale, flow._replace(se=se, cnt=cnt)
@@ -6102,7 +6098,7 @@ def _flow_insert_core(
     step instead masks the hit lanes out.  Eligible-lane identity and
     relative order are the same either way, so winner selection and the
     counter segment sums stay bit-identical."""
-    C = flow.se.shape[0]
+    C = flow.se.shape[1]
     page = _arena_pages(page_table, tenant)
     keyw = flow_key_words(batch, tenant)
     is_ip = (batch.kind == KIND_IPV4) | (batch.kind == KIND_IPV6)
@@ -6115,23 +6111,24 @@ def _flow_insert_core(
     if lane_ok is not None:
         elig = elig & lane_ok
     cand = _flow_slots(keyw, page, slab_entries=slab_entries, ways=ways)
-    ek = jnp.take(flow.keys, cand, axis=0, mode="clip")
-    ese = jnp.take(flow.se, cand, axis=0, mode="clip")
-    est = ese[:, :, 0]
-    eep = ese[:, :, 1]
-    match_w = jnp.all(ek == keyw[:, None, :], axis=2) & (est > 0)
+    ek = jnp.take(flow.keys, cand, axis=1, mode="clip")
+    ese = jnp.take(flow.se, cand, axis=1, mode="clip")
+    est = ese[0]
+    eep = ese[1]
+    match_w = jnp.all(ek == keyw[:, None, :], axis=0) & (est > 0)
     empty_w = est == 0
     W = ways
-    widx = jnp.arange(W, dtype=jnp.int32)[None, :]
-    m_first = jnp.min(jnp.where(match_w, widx, W), axis=1)
-    e_first = jnp.min(jnp.where(empty_w, widx, W), axis=1)
-    oldest = jnp.argmin(eep, axis=1).astype(jnp.int32)
+    widx = jnp.arange(W, dtype=jnp.int32)[:, None]
+    m_first = jnp.min(jnp.where(match_w, widx, W), axis=0)
+    e_first = jnp.min(jnp.where(empty_w, widx, W), axis=0)
+    oldest = jnp.argmin(eep, axis=0).astype(jnp.int32)
     way = jnp.where(
         m_first < W, m_first, jnp.where(e_first < W, e_first, oldest)
     )
-    slot = jnp.sum(jnp.where(widx == way[:, None], cand, 0), axis=1)
+    at_way = widx == way[None, :]
+    slot = jnp.sum(jnp.where(at_way, cand, 0), axis=0)
     matched = m_first < W
-    old_state = jnp.sum(jnp.where(widx == way[:, None], est, 0), axis=1)
+    old_state = jnp.sum(jnp.where(at_way, est, 0), axis=0)
 
     # last eligible lane per slot wins the .set() writes
     lane = jnp.arange(slot.shape[0], dtype=jnp.int32)
@@ -6144,10 +6141,8 @@ def _flow_insert_core(
 
     # per-slot batch contributions (counter seeds) over ALL eligible lanes
     ln = batch.pkt_len
-    seeds = jnp.zeros((C, 3), jnp.int32).at[idx_e].add(
-        jnp.stack(
-            [jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF], axis=1
-        ),
+    seeds = jnp.zeros((3, C), jnp.int32).at[:, idx_e].add(
+        jnp.stack([jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF]),
         mode="drop",
     )
 
@@ -6157,23 +6152,20 @@ def _flow_insert_core(
     ).astype(jnp.int32)
     mygen = jnp.take(gens, jnp.clip(tenant, 0, gens.shape[0] - 1),
                      mode="clip")
-    keys = flow.keys.at[idx_w].set(keyw, mode="drop")
-    vg = flow.vg.at[idx_w].set(
-        jnp.stack(
-            [(verdict16.astype(jnp.int32)) & 0xFFFF, mygen], axis=1
-        ),
+    keys = flow.keys.at[:, idx_w].set(keyw, mode="drop")
+    vg = flow.vg.at[:, idx_w].set(
+        jnp.stack([(verdict16.astype(jnp.int32)) & 0xFFFF, mygen]),
         mode="drop",
     )
-    se = flow.se.at[idx_w].set(
-        jnp.stack(
-            [state_val,
-             jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32)],
-            axis=1,
-        ),
+    se = flow.se.at[:, idx_w].set(
+        jnp.stack([
+            state_val,
+            jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32),
+        ]),
         mode="drop",
     )
-    cnt = flow.cnt.at[idx_w].set(
-        jnp.take(seeds, jnp.clip(slot, 0, C - 1), axis=0, mode="clip"),
+    cnt = flow.cnt.at[:, idx_w].set(
+        jnp.take(seeds, jnp.clip(slot, 0, C - 1), axis=1, mode="clip"),
         mode="drop",
     )
 
@@ -6208,11 +6200,9 @@ def jitted_flow_age():
     last seen strictly before ``cutoff`` free their slot.  Returns
     (new se, aged count)."""
     def f(se, cutoff):
-        expire = (se[:, 0] > 0) & (se[:, 1] < cutoff)
+        expire = (se[0] > 0) & (se[1] < cutoff)
         return (
-            jnp.where(expire[:, None], jnp.stack(
-                [jnp.zeros_like(se[:, 0]), se[:, 1]], axis=1
-            ), se),
+            se.at[0].set(jnp.where(expire, FLOW_EMPTY, se[0])),
             jnp.sum(expire.astype(jnp.int32)),
         )
 
@@ -6221,7 +6211,7 @@ def jitted_flow_age():
 
 @functools.lru_cache(maxsize=None)
 def jitted_flow_occupancy():
-    return jax.jit(lambda se: jnp.sum((se[:, 0] > 0).astype(jnp.int32)))
+    return jax.jit(lambda se: jnp.sum((se[0] > 0).astype(jnp.int32)))
 
 
 # === resident serving step (zero-copy donated-buffer loop, ISSUE-12) =========

@@ -749,18 +749,19 @@ def arena_replicated(mesh: Mesh) -> NamedSharding:
 # --- stateful flow tier (ISSUE-11) ------------------------------------------
 #
 # The flow slab family's partition rules, declared once like the arena
-# pools: flow columns row-shard over "rules" when the row count divides
+# pools: flow columns slot-shard over "rules" when the slot count divides
 # the axis (capacity scales with it; the probe/insert gathers and
 # scatters engage GSPMD under the SAME jitted factories the single chip
 # uses), while the small per-tenant steering state (generation vector,
 # flow page table) replicates like the arena page table.
 
 FLOW_PARTITION_RULES = {
-    # the FlowTable columns (jaxpath.FlowTable: keys / vg / se / cnt)
-    "keys": P("rules", None),
-    "vg": P("rules", None),
-    "se": P("rules", None),
-    "cnt": P("rules", None),
+    # the slot-minor FlowTable columns (jaxpath.FlowTable: keys / vg /
+    # se / cnt), slots on the minor axis
+    "keys": P(None, "rules"),
+    "vg": P(None, "rules"),
+    "se": P(None, "rules"),
+    "cnt": P(None, "rules"),
     # per-tenant steering state: replicated like the arena page table
     "gens": P(),
     "page_table": P(),
@@ -769,7 +770,7 @@ FLOW_PARTITION_RULES = {
 
 
 def flow_shardings(mesh: Mesh, capacity: int):
-    """Per-column NamedShardings for a flow tier on ``mesh``: rows over
+    """Per-column NamedShardings for a flow tier on ``mesh``: slots over
     "rules" when the capacity divides the axis, else fully replicated —
     the degrade-never-refuse posture of the arena placement."""
     rules = mesh.shape["rules"]

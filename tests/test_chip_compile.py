@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from infw import kernels, testing
 from infw.daemon import DEFAULT_INGEST_CHUNK
+from infw.flow import FlowConfig, FlowTier
 from infw.kernels import jaxpath, pallas_dense, pallas_walk
 from infw.parallel import mesh as meshmod
 
@@ -130,6 +131,37 @@ def test_resident_step(one_chip):
     for m in filter(None, map(opcode.match, text.splitlines())):
         if m.group(2) not in structural:
             assert stages[m.group(1)] != "other", m.group(0)[:200]
+
+
+def test_resident_step_flow_stages_lane_dense(one_chip):
+    """The resident step at the benchmark cell's shapes (dense path,
+    65,536-packet records, a 16,384-slot 4-way flow table, flow columns
+    donated): no copy in the flow_probe or flow_insert stage has the
+    batch and the ways as its only dimensions above one.  Stored
+    slot-major, (C, k), each way-gathered column came out as (B, W, 1)
+    with the ways on the lanes and took such a relayout copy before its
+    consumers.  The 2-word state and verdict gathers still leave the
+    gather as (W * B, 2) rows and are relayouted to (2, W, B) planes;
+    this test does not pin those."""
+    b = 65536
+    cfg = FlowConfig.make(entries=16384)
+    tier = FlowTier(cfg)
+    lane = jax.ShapeDtypeStruct((b,), jnp.int32)
+    fn = jaxpath.jitted_resident_step(cfg.entries, cfg.ways, "dense")
+    args = (tier._flow, tier._gens_dev, tier._pages_dev, np.int32(0),
+            kernels._fixture_device_tables(False),
+            jax.ShapeDtypeStruct((b, 7), jnp.uint32), lane, lane,
+            tier._max_age_dev)
+    text = compile_for(fn, *shapes(args, one_chip)).as_text()
+    stages = jaxpath.hlo_stage_map(text)
+    assert {"flow_probe", "flow_insert"} <= set(stages.values())
+    copy = re.compile(
+        r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+\w+\[([\d,]*)\]\S*\s+copy\(")
+    for m in filter(None, map(copy.match, text.splitlines())):
+        dims = sorted(int(d) for d in m.group(2).split(",") if d)
+        if stages[m.group(1)] in ("flow_probe", "flow_insert"):
+            assert [d for d in dims if d > 1] != sorted([cfg.ways, b]), (
+                m.group(0)[:200])
 
 
 def test_mesh_walk_on_four_chips(topo, cache_off):

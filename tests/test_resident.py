@@ -142,6 +142,52 @@ def test_resident_tcp_flags_and_v4_compact(shared):
         assert np.array_equal(a[k], b[k]), f"flow column {k} diverged"
 
 
+def test_resident_slot_minor_columns_over_flow_events():
+    """The device keeps the flow columns slot-minor; resident steps over
+    lanes that hit, go stale, FIN, RST, evict and promote leave
+    flow_columns() equal to the host model's (C, k) columns and to the
+    classic plan's, with the classic plan's verdicts, statistics and
+    flow counters out of the fused readbacks."""
+    tabs = _tables(v6=0.3)
+    res, multi = _pair(tabs, flow_track_model=True)
+    batch, _meta = testing.flow_trace_batch(
+        np.random.default_rng(23), tabs, 1024, 0.7, chunk_packets=256,
+        fin_fraction=0.2,
+    )
+    ref = oracle.classify(tabs, batch)
+    for c, lo in enumerate(range(0, len(batch), 256)):
+        sub = batch.slice(lo, lo + 256)
+        if c == 2:  # RST on a third of the TCP lanes
+            tcp = np.nonzero(np.asarray(sub.proto) == 6)[0][::3]
+            sub.tcp_flags = np.asarray(sub.tcp_flags).copy()
+            sub.tcp_flags[tcp] = jaxpath.TCP_RST | jaxpath.TCP_ACK
+        if c == 3:  # every cached verdict goes stale
+            res.flow.bump_generation(0)
+            multi.flow.bump_generation(0)
+        o = res.classify(sub, apply_stats=False)
+        om = multi.classify(sub, apply_stats=False)
+        assert np.array_equal(o.results, ref.results[lo : lo + 256]), c
+        assert np.array_equal(o.results, om.results), c
+        assert np.array_equal(o.xdp, om.xdp), c
+        assert np.array_equal(o.stats_delta, om.stats_delta), c
+    got, want = res.flow.stats.values(), multi.flow.stats.values()
+    for k in ("hits", "misses", "inserts", "evictions", "promotes",
+              "stale_rejects"):
+        assert got[k] == want[k] > 0, (k, got[k], want[k])
+    model = res.flow.model
+    cols, classic = res.flow.flow_columns(), multi.flow.flow_columns()
+    for k, m in model.columns().items():
+        assert cols[k].shape == m.shape == (512, m.shape[1]), k
+        assert np.array_equal(cols[k], m), f"flow column {k} != model"
+        assert np.array_equal(classic[k], m), f"classic column {k} != model"
+    assert (model.se[:, 0] == jaxpath.FLOW_FIN).any()
+    # RST teardown empties a slot that still holds its counters
+    assert ((model.se[:, 0] == jaxpath.FLOW_EMPTY)
+            & (model.cnt[:, 0] > 0)).any()
+    res.close()
+    multi.close()
+
+
 @pytest.mark.slow
 def test_resident_dense_and_ctrie_paths():
     """The resident program covers all three layout paths: the dense
