@@ -33,6 +33,7 @@ from .._threads import spawn
 from ..compiler import CompiledTables
 from ..constants import ALLOW, DENY, KIND_IPV6
 from ..kernels import jaxpath, pallas_dense, pallas_walk, wire_decode
+from ..obs import program_span
 from ..packets import PacketBatch, encode_delta_wire, narrow_wire, wire8
 from .base import ClassifyOutput, PendingClassify, StatsAccumulator
 
@@ -50,6 +51,9 @@ class TpuClassifier:
     #: the syncer may route structurally-new keys to a dense side-table
     #: (load_tables(..., overlay=...), jaxpath.classify_with_overlay)
     supports_overlay = True
+    #: the daemon's SpanTracer (set by the syncer under --trace): a
+    #: load's device upload and walk build as infw.load.* program spans
+    tracer = None
 
     def __init__(
         self,
@@ -406,142 +410,144 @@ class TpuClassifier:
                 # Pallas packing: serve it from the trie path instead of
                 # refusing the table at load time.
                 path = "trie"
-        if path == "dense":
-            dev = jax.tree.map(lambda a: jax.device_put(a, self._device), pt)
-            block_b = pallas_dense.choose_block_b(pt.mdt.shape[1])
-            self._last_load = ("full", tables.num_entries)
-        elif path == "ctrie":
-            # Compressed-poptrie resident form: dev is (CTrieTables,
-            # d_max) — d_max is the static walk-unroll bound and travels
-            # beside the pytree, not inside it.  Same incremental
-            # contract as the trie path: rules-only edits scatter the
-            # per-tidx joined rows, structural edits diff the merged
-            # node/target arrays row-wise; a layout shift past the row
-            # buckets (or a d_max change) re-uploads.
-            dev = None
-            block_b = None
-            with self._lock:
-                prev_tables, prev_active = self._tables, self._active
-            if (
-                prev_tables is not None
-                and prev_active is not None
-                and prev_active[0] == "ctrie"
-            ):
-                patched = jaxpath.patch_ctrie(
-                    prev_active[1][0], prev_tables, tables, self._device,
-                    hint=dirty_hint,
-                )
-                if patched is None and jaxpath.hint_trie_unchanged(
-                    dirty_hint
+        with program_span(self.tracer, "infw.load.upload"):
+            if path == "dense":
+                dev = jax.tree.map(lambda a: jax.device_put(a, self._device), pt)
+                block_b = pallas_dense.choose_block_b(pt.mdt.shape[1])
+                self._last_load = ("full", tables.num_entries)
+            elif path == "ctrie":
+                # Compressed-poptrie resident form: dev is (CTrieTables,
+                # d_max) — d_max is the static walk-unroll bound and travels
+                # beside the pytree, not inside it.  Same incremental
+                # contract as the trie path: rules-only edits scatter the
+                # per-tidx joined rows, structural edits diff the merged
+                # node/target arrays row-wise; a layout shift past the row
+                # buckets (or a d_max change) re-uploads.
+                dev = None
+                block_b = None
+                with self._lock:
+                    prev_tables, prev_active = self._tables, self._active
+                if (
+                    prev_tables is not None
+                    and prev_active is not None
+                    and prev_active[0] == "ctrie"
                 ):
-                    # only a rules-only hint takes a different path on
-                    # retry (structural row-diff instead of the joined
-                    # fast path); a structural hint already ran exactly
-                    # the diff a no-hint attempt would repeat
                     patched = jaxpath.patch_ctrie(
-                        prev_active[1][0], prev_tables, tables, self._device
+                        prev_active[1][0], prev_tables, tables, self._device,
+                        hint=dirty_hint,
                     )
-                if patched is not None:
-                    dev = (patched[0], prev_active[1][1])
-                    self._last_load = ("patch", patched[1])
-            if dev is None:
-                dev = jaxpath.device_ctrie(tables, self._device, pad=True)
-                self._last_load = ("full", tables.num_entries)
-                # same first-edit contract as the level walk: the patch
-                # scatters compile at load time, not on the first edit
-                jaxpath.warm_ctrie_patch_scatters(dev[0], self._device)
-        else:
-            try:
-                jaxpath.check_wire_ruleids(tables)
-            except ValueError:
-                # ruleIds > 255: the 2B wire result can't carry them —
-                # fall back to the u32 (non-wire) classify path.
-                wide_rids = True
-            dev = None
-            with self._lock:
-                prev_tables, prev_active = self._tables, self._active
-            if (
-                prev_tables is not None
-                and prev_active is not None
-                and prev_active[0] == "trie"
-            ):
-                # Incremental device patch (the Map.Update analogue):
-                # ship only the rows that changed since the resident
-                # generation; falls back to a full upload on structural
-                # change (trie growth, compaction, path flip).
-                patched = jaxpath.patch_device_tables(
-                    prev_active[1], prev_tables, tables, self._device,
-                    hint=dirty_hint,
-                )
-                if patched is None and dirty_hint is not None:
-                    # hint didn't apply (bucket growth / oversized delta):
-                    # try the diff-based patch before a full re-upload
+                    if patched is None and jaxpath.hint_trie_unchanged(
+                        dirty_hint
+                    ):
+                        # only a rules-only hint takes a different path on
+                        # retry (structural row-diff instead of the joined
+                        # fast path); a structural hint already ran exactly
+                        # the diff a no-hint attempt would repeat
+                        patched = jaxpath.patch_ctrie(
+                            prev_active[1][0], prev_tables, tables, self._device
+                        )
+                    if patched is not None:
+                        dev = (patched[0], prev_active[1][1])
+                        self._last_load = ("patch", patched[1])
+                if dev is None:
+                    dev = jaxpath.device_ctrie(tables, self._device, pad=True)
+                    self._last_load = ("full", tables.num_entries)
+                    # same first-edit contract as the level walk: the patch
+                    # scatters compile at load time, not on the first edit
+                    jaxpath.warm_ctrie_patch_scatters(dev[0], self._device)
+            else:
+                try:
+                    jaxpath.check_wire_ruleids(tables)
+                except ValueError:
+                    # ruleIds > 255: the 2B wire result can't carry them —
+                    # fall back to the u32 (non-wire) classify path.
+                    wide_rids = True
+                dev = None
+                with self._lock:
+                    prev_tables, prev_active = self._tables, self._active
+                if (
+                    prev_tables is not None
+                    and prev_active is not None
+                    and prev_active[0] == "trie"
+                ):
+                    # Incremental device patch (the Map.Update analogue):
+                    # ship only the rows that changed since the resident
+                    # generation; falls back to a full upload on structural
+                    # change (trie growth, compaction, path flip).
                     patched = jaxpath.patch_device_tables(
-                        prev_active[1], prev_tables, tables, self._device
+                        prev_active[1], prev_tables, tables, self._device,
+                        hint=dirty_hint,
                     )
-                if patched is not None:
-                    dev, n_rows = patched
-                    self._last_load = ("patch", n_rows)
-            if dev is None:
-                # pad=True buckets device row counts so later small edits
-                # keep array shapes and can take the patch path
-                dev = jaxpath.device_tables(tables, self._device, pad=True)
-                self._last_load = ("full", tables.num_entries)
-                # Pre-compile the patch scatters against the fresh layout:
-                # the first post-load rule edit then ships in milliseconds
-                # instead of paying the scatter-jit compile (the pinned-map
-                # re-adoption contract is rules keep enforcing AND stay
-                # editable immediately, loader.go:381-407).
-                jaxpath.warm_patch_scatters(dev, self._device)
-            block_b = None
+                    if patched is None and dirty_hint is not None:
+                        # hint didn't apply (bucket growth / oversized delta):
+                        # try the diff-based patch before a full re-upload
+                        patched = jaxpath.patch_device_tables(
+                            prev_active[1], prev_tables, tables, self._device
+                        )
+                    if patched is not None:
+                        dev, n_rows = patched
+                        self._last_load = ("patch", n_rows)
+                if dev is None:
+                    # pad=True buckets device row counts so later small edits
+                    # keep array shapes and can take the patch path
+                    dev = jaxpath.device_tables(tables, self._device, pad=True)
+                    self._last_load = ("full", tables.num_entries)
+                    # Pre-compile the patch scatters against the fresh layout:
+                    # the first post-load rule edit then ships in milliseconds
+                    # instead of paying the scatter-jit compile (the pinned-map
+                    # re-adoption contract is rules keep enforcing AND stay
+                    # editable immediately, loader.go:381-407).
+                    jaxpath.warm_patch_scatters(dev, self._device)
+                block_b = None
         steer_parts = None
         walk_dev = None
         walk_meta = None
         defer_walk = False
-        if path in ("trie", "ctrie"):
-            # per-root-slot deep-level requirement (conservative across
-            # rules-only patches via the cache carry-forward; recomputed
-            # from the snapshot's slot arrays on structural loads);
-            # thresholds are TUNED to this table's depth histogram
-            # (jaxpath.tune_depth_classes) rather than the static set.
-            # The LUT is in LEVEL terms — conservative for the
-            # compressed walk, whose skip nodes only shrink step counts.
-            lut = jaxpath.build_depth_lut(tables)
-            classes = jaxpath.tune_depth_classes(tables)
-            steer_parts = (
-                np.asarray(tables.root_lut, np.int64),
-                lut,
-                classes,
-            )
-            if self._fused_deep and not wide_rids:
-                structural_patch = dirty_hint is not None and any(
-                    len(h) for h in dirty_hint.get("levels", ())
+        with program_span(self.tracer, "infw.load.walk"):
+            if path in ("trie", "ctrie"):
+                # per-root-slot deep-level requirement (conservative across
+                # rules-only patches via the cache carry-forward; recomputed
+                # from the snapshot's slot arrays on structural loads);
+                # thresholds are TUNED to this table's depth histogram
+                # (jaxpath.tune_depth_classes) rather than the static set.
+                # The LUT is in LEVEL terms — conservative for the
+                # compressed walk, whose skip nodes only shrink step counts.
+                lut = jaxpath.build_depth_lut(tables)
+                classes = jaxpath.tune_depth_classes(tables)
+                steer_parts = (
+                    np.asarray(tables.root_lut, np.int64),
+                    lut,
+                    classes,
                 )
-                if structural_patch:
-                    # A structural incremental edit (CIDR delete, overlay
-                    # merge) must stay at diff-scatter-patch latency: the
-                    # full walk rebuild (depth LUT + extraction + byte
-                    # packing + upload) runs in the BACKGROUND and
-                    # installs when ready; until then the full-depth
-                    # class takes the XLA walk — the fallback contract,
-                    # never a wrong verdict.
-                    defer_walk = True
-                else:
-                    walk_dev, walk_meta = self._build_walk(
-                        tables, classes, dirty_hint, path == "ctrie"
+                if self._fused_deep and not wide_rids:
+                    structural_patch = dirty_hint is not None and any(
+                        len(h) for h in dirty_hint.get("levels", ())
                     )
-                    if walk_dev is not None:
-                        # pre-compile the walk's joined-plane patch
-                        # scatters (one per array shape, lru-deduped) so
-                        # the first fused-path rules edit is compile-free
-                        if path == "ctrie":
-                            pallas_walk.warm_cwalk_patch_scatters(
-                                walk_dev[0], self._device
-                            )
-                        else:
-                            pallas_walk.warm_walk_patch_scatters(
-                                walk_dev, self._device
-                            )
+                    if structural_patch:
+                        # A structural incremental edit (CIDR delete, overlay
+                        # merge) must stay at diff-scatter-patch latency: the
+                        # full walk rebuild (depth LUT + extraction + byte
+                        # packing + upload) runs in the BACKGROUND and
+                        # installs when ready; until then the full-depth
+                        # class takes the XLA walk — the fallback contract,
+                        # never a wrong verdict.
+                        defer_walk = True
+                    else:
+                        walk_dev, walk_meta = self._build_walk(
+                            tables, classes, dirty_hint, path == "ctrie"
+                        )
+                        if walk_dev is not None:
+                            # pre-compile the walk's joined-plane patch
+                            # scatters (one per array shape, lru-deduped) so
+                            # the first fused-path rules edit is compile-free
+                            if path == "ctrie":
+                                pallas_walk.warm_cwalk_patch_scatters(
+                                    walk_dev[0], self._device
+                                )
+                            else:
+                                pallas_walk.warm_walk_patch_scatters(
+                                    walk_dev, self._device
+                                )
         ov_dev = None
         if overlay is not None and overlay.num_entries > 0:
             if path not in ("trie", "ctrie") or wide_rids:
@@ -1166,6 +1172,7 @@ class TpuClassifier:
                 d = int(dclass)
         n = wire_np.shape[0]
         kind = (wire_np[:, 0] & 3).astype(np.int32)
+        v4_walk = bool(v4_only) and ctx.path == "trie"
         tel = self._telemetry
         ml = self._mlscore
         pt = self._payload
@@ -1176,8 +1183,8 @@ class TpuClassifier:
                 payload, payload_len, pt.spec.plen
             )
         fn = jaxpath.jitted_resident_step(
-            tier.config.entries, tier.config.ways, ctx.path,
-            bool(v4_only) and ctx.path == "trie", d, ctx.d_max,
+            tier.config.entries, tier.config.ways, ctx.path, v4_walk, d,
+            ctx.d_max,
             ctx.ov_dev is not None,
             sketch=tel.spec if tel is not None else None,
             score=ml.spec if ml is not None else None,
@@ -1207,6 +1214,7 @@ class TpuClassifier:
         )
         pool.note("dispatches")
         pool.note(f"slot{(epoch - 1) & 1}_dispatches")
+        pool.note_walk(ctx, kind, v4_walk, d)
         try:
             fused.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -1293,6 +1301,8 @@ class TpuClassifier:
         pool.note("dispatches")
         pool.note("superbatch_dispatches")
         pool.note("superbatch_admissions", k)
+        kinds = (wire_stack[:, :, 0] & 3).astype(np.int32)
+        pool.note_walk(ctx, kinds, bool(v4_only) and ctx.path == "trie", None)
         try:
             fused.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -1302,7 +1312,6 @@ class TpuClassifier:
             self._note_wire(
                 "payload", k * n, pay_np.nbytes + plen_np.nbytes
             )
-        kinds = (wire_stack[:, :, 0] & 3).astype(np.int32)
         pkt_lens = [self._wire4_pkt_len(wire_stack[j]) for j in range(k)]
         return {"resident_super": True, "fused": fused, "k": k, "n": n,
                 "kinds": kinds, "epoch0": epoch - k,

@@ -37,7 +37,6 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -49,6 +48,7 @@ from .compiler import CompileError
 from .constants import DENY, KIND_IPV6, KIND_OTHER, MAX_TARGETS
 from .interfaces import InterfaceError, InterfaceRegistry, default_registry
 from .nodestate_controller import NodeStateReconciler
+from .obs import program_span as _span
 from .obs.events import EventRing, EventsLogger, emit_deny_events
 from .obs.pcap import FramesBuf, parse_frames_buf
 from .obs.statistics import Registry as MetricsRegistry, Statistics
@@ -387,16 +387,6 @@ class _FlowCounters:
         if not self._prefix:
             return vals
         return {f"{self._prefix}{k}": v for k, v in vals.items()}
-
-
-#: what a span site enters when tracing is off
-_NO_SPAN = nullcontext()
-
-
-def _span(tracer, name: str, seq: Optional[int] = None):
-    """The tracer's program span (SpanTracer.span), or a no-op when the
-    daemon runs without ``--trace``."""
-    return _NO_SPAN if tracer is None else tracer.span(name, seq)
 
 
 def _batch_from_wire(wire: np.ndarray, tcp_flags=None) -> PacketBatch:
@@ -763,6 +753,7 @@ class Daemon:
             registry=self.registry,
             stats_poller=self.stats,
             checkpoint_dir=os.path.join(state_dir, "checkpoint"),
+            tracer=self.tracer,
         )
         self.store = store if store is not None else InMemoryStore()
         self.reconciler = NodeStateReconciler(
@@ -940,10 +931,12 @@ class Daemon:
                 continue
             if self._rejected_state_files.get(fn) == mtime:
                 continue
+            tracer = getattr(self, "tracer", None)
             try:
-                with open(path) as f:
-                    doc = json.load(f)
-                ns_obj = IngressNodeFirewallNodeState.from_dict(doc)
+                with _span(tracer, "infw.load.parse"):
+                    with open(path) as f:
+                        doc = json.load(f)
+                    ns_obj = IngressNodeFirewallNodeState.from_dict(doc)
             except OSError as e:
                 # I/O errors can be transient; retry next tick.
                 log.error("bad nodestate file %s: %s", fn, e)
@@ -960,7 +953,8 @@ class Daemon:
                 ns_obj.metadata.namespace = self.namespace
             if ns_obj.metadata.name != self.node_name:
                 continue
-            schema_errs = validate_nodestate_schema(ns_obj)
+            with _span(tracer, "infw.load.parse"):
+                schema_errs = validate_nodestate_schema(ns_obj)
             if schema_errs:
                 # The file protocol has no API server in front of it; apply
                 # the schema tier here so a misspelled protocol or order=0

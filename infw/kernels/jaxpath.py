@@ -952,44 +952,45 @@ def _ctrie_descend(
     win = jnp.zeros_like(node)  # flat target position (0 = sentinel)
 
     for _step in range(d_max):
-        in_n = (node >= 0) & (node < nodes.shape[0])
-        alive = alive & in_n
-        r = jnp.take(nodes, node, axis=0, mode="clip")
-        skip_len = r[:, 2].astype(jnp.int32)
-        skip_ok = jnp.where(
-            skip_len > 0,
-            extract_ip_bits(batch.ip_words, pos, skip_len) == r[:, 3],
-            True,
-        )
-        alive = alive & skip_ok
-        pos = pos + skip_len
-        nib = extract_ip_bits(
-            batch.ip_words, pos, jnp.full_like(pos, 8)
-        ).astype(jnp.int32)
-        pos = pos + 8
-        w = (nib >> 5)[:, None]
-        below = (np.uint32(1) << (nib & 31).astype(jnp.uint32)) - 1
-        cb = r[:, 4:12]
-        tb = r[:, 12:20]
-        pc_cb = _popcount32(cb)
-        pc_tb = _popcount32(tb)
-        prefix = jnp.sum(jnp.where(widx8 < w, pc_cb, 0), axis=1)
-        tprefix = jnp.sum(jnp.where(widx8 < w, pc_tb, 0), axis=1)
-        cw = jnp.sum(jnp.where(widx8 == w, cb, 0), axis=1)
-        tw = jnp.sum(jnp.where(widx8 == w, tb, 0), axis=1)
-        bit = (nib & 31).astype(jnp.uint32)
-        ok_t = alive & (((tw >> bit) & 1) > 0) & (pos <= cap_bits)
-        win = jnp.where(
-            ok_t,
-            (r[:, 1] + tprefix + _popcount32(tw & below)).astype(jnp.int32),
-            win,
-        )
-        alive = alive & (((cw >> bit) & 1) > 0)
-        node = jnp.where(
-            alive,
-            (r[:, 0] + prefix + _popcount32(cw & below)).astype(jnp.int32),
-            0,
-        )
+        with jax.named_scope("lpm_level"):
+            in_n = (node >= 0) & (node < nodes.shape[0])
+            alive = alive & in_n
+            r = jnp.take(nodes, node, axis=0, mode="clip")
+            skip_len = r[:, 2].astype(jnp.int32)
+            skip_ok = jnp.where(
+                skip_len > 0,
+                extract_ip_bits(batch.ip_words, pos, skip_len) == r[:, 3],
+                True,
+            )
+            alive = alive & skip_ok
+            pos = pos + skip_len
+            nib = extract_ip_bits(
+                batch.ip_words, pos, jnp.full_like(pos, 8)
+            ).astype(jnp.int32)
+            pos = pos + 8
+            w = (nib >> 5)[:, None]
+            below = (np.uint32(1) << (nib & 31).astype(jnp.uint32)) - 1
+            cb = r[:, 4:12]
+            tb = r[:, 12:20]
+            pc_cb = _popcount32(cb)
+            pc_tb = _popcount32(tb)
+            prefix = jnp.sum(jnp.where(widx8 < w, pc_cb, 0), axis=1)
+            tprefix = jnp.sum(jnp.where(widx8 < w, pc_tb, 0), axis=1)
+            cw = jnp.sum(jnp.where(widx8 == w, cb, 0), axis=1)
+            tw = jnp.sum(jnp.where(widx8 == w, tb, 0), axis=1)
+            bit = (nib & 31).astype(jnp.uint32)
+            ok_t = alive & (((tw >> bit) & 1) > 0) & (pos <= cap_bits)
+            win = jnp.where(
+                ok_t,
+                (r[:, 1] + tprefix + _popcount32(tw & below)).astype(jnp.int32),
+                win,
+            )
+            alive = alive & (((cw >> bit) & 1) > 0)
+            node = jnp.where(
+                alive,
+                (r[:, 0] + prefix + _popcount32(cw & below)).astype(jnp.int32),
+                0,
+            )
     return win
 
 
@@ -1002,28 +1003,32 @@ def ctrie_walk_rows(
     all-zero -> UNDEF), bit-identical in verdict semantics to
     trie_walk_joined."""
     l0, nodes, targets, joined, root_lut = cdev
-    lut_size = root_lut.shape[0]
-    if_ok = (batch.ifindex >= 0) & (batch.ifindex < lut_size)
-    root = jnp.where(
-        if_ok, jnp.take(root_lut, jnp.clip(batch.ifindex, 0, lut_size - 1)), 0
-    )
-    nib0 = (batch.ip_words[:, 0] >> np.uint32(16)).astype(jnp.int32)
-    e0 = root * 65536 + nib0
-    in0 = (e0 >= 0) & (e0 < l0.shape[0])
-    rows0 = jnp.take(l0, e0, axis=0, mode="clip")
-    best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1], 0)  # tidx+1
-    alive = in0 & (rows0[:, 0] > 0)
-    node = jnp.where(alive, rows0[:, 0] - 1, 0)
+    with jax.named_scope("lpm_root"):
+        lut_size = root_lut.shape[0]
+        if_ok = (batch.ifindex >= 0) & (batch.ifindex < lut_size)
+        root = jnp.where(
+            if_ok, jnp.take(root_lut, jnp.clip(batch.ifindex, 0, lut_size - 1)),
+            0,
+        )
+        nib0 = (batch.ip_words[:, 0] >> np.uint32(16)).astype(jnp.int32)
+        e0 = root * 65536 + nib0
+        in0 = (e0 >= 0) & (e0 < l0.shape[0])
+        rows0 = jnp.take(l0, e0, axis=0, mode="clip")
+        best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1], 0)  # tidx+1
+        alive = in0 & (rows0[:, 0] > 0)
+        node = jnp.where(alive, rows0[:, 0] - 1, 0)
     win = _ctrie_descend(nodes, batch, node, alive, d_max)
 
-    in_w = (win >= 0) & (win < targets.shape[0])
-    tval = jnp.where(in_w, jnp.take(targets, jnp.clip(win, 0), mode="clip"), 0)
-    sel = jnp.where(tval > 0, tval, best0)  # tidx+1 (0 = no match)
-    in_j = (sel > 0) & (sel < joined.shape[0])
-    rows = jnp.take(
-        joined, jnp.clip(sel, 0, joined.shape[0] - 1), axis=0, mode="clip"
-    )
-    return jnp.where(in_j[:, None], rows, 0)
+    with jax.named_scope("lpm_resolve"):
+        in_w = (win >= 0) & (win < targets.shape[0])
+        tval = jnp.where(in_w, jnp.take(targets, jnp.clip(win, 0), mode="clip"),
+                         0)
+        sel = jnp.where(tval > 0, tval, best0)  # tidx+1 (0 = no match)
+        in_j = (sel > 0) & (sel < joined.shape[0])
+        rows = jnp.take(
+            joined, jnp.clip(sel, 0, joined.shape[0] - 1), axis=0, mode="clip"
+        )
+        return jnp.where(in_j[:, None], rows, 0)
 
 
 def _ctrie_result_and_score(cdev: CTrieTables, batch: DeviceBatch, d_max: int):
@@ -2375,6 +2380,15 @@ def v4_trie_depth(n_levels: int) -> int:
     return max(1, depth)
 
 
+def walk_levels(n_levels: int, v4_only: bool, depth: Optional[int]) -> int:
+    """Trie levels (the DIR-16 root counted) the resident step walks on
+    a table of ``n_levels``: a v4-only batch stops at the /32 boundary,
+    a depth class at its deepest level, any other batch walks them all."""
+    if v4_only:
+        return v4_trie_depth(n_levels)
+    return n_levels if depth is None else 1 + depth
+
+
 def classify_wire(
     tables: DeviceTables, wire: jax.Array, *, use_trie: bool,
     v4_only: bool = False, depth: Optional[int] = None,
@@ -2557,6 +2571,80 @@ def _popcount32(x: jax.Array) -> jax.Array:
     return (x * np.uint32(0x01010101)) >> 24
 
 
+def _poptrie_descend(trie_levels, root_lut: jax.Array, batch: DeviceBatch):
+    """The level loop trie_walk and trie_walk_joined share: the DIR-16
+    root gather (stage ``lpm_root``), then per level one node-row
+    gather, the bitmap rank and the win update (``lpm_level``).  Returns
+    (in0, rows0, win): the root range check, the root slot rows and the
+    deepest target position the levels found (uint32, 0: none); the
+    callers resolve it in ``lpm_resolve``."""
+    strides = trie_level_strides(len(trie_levels))
+    with jax.named_scope("lpm_root"):
+        lut_size = root_lut.shape[0]
+        if_ok = (batch.ifindex >= 0) & (batch.ifindex < lut_size)
+        root = jnp.where(
+            if_ok, jnp.take(root_lut, jnp.clip(batch.ifindex, 0, lut_size - 1)),
+            0,
+        )
+        # OOB policy for every gather in the walk: indices are in-range
+        # by construction (child ranks only reach allocated nodes; dead
+        # lanes pin to 0; build_poptrie bounds the root level so e0
+        # below cannot wrap int32), and should a future build bug break
+        # that, the lane is INVALIDATED — an explicit range mask forces
+        # it to UNDEF, i.e. XDP PASS (deterministic, never a
+        # wrong-verdict read; note this default is allow, matching the
+        # kernel's no-match semantics, kernel.c:453).
+        nib0 = (batch.ip_words[:, 0] >> np.uint32(16)).astype(jnp.int32)
+        e0 = root * 65536 + nib0
+        in0 = (e0 >= 0) & (e0 < trie_levels[0].shape[0])
+        rows0 = jnp.take(trie_levels[0], e0, axis=0, mode="clip")
+        alive = in0 & (rows0[:, 0] > 0)  # child ids are stored +1 (0 = none)
+        node = jnp.where(alive, rows0[:, 0] - 1, 0)
+        cap_bits = jnp.where(batch.kind == KIND_IPV4, 32, 128)
+        win = jnp.zeros_like(node, dtype=jnp.uint32)  # targets[0] sentinel
+    widx8 = jnp.arange(8, dtype=jnp.int32)[None, :]
+
+    bit_end = strides[0]
+    for stride, tbl in zip(strides[1:], trie_levels[1:]):
+        bit_start, bit_end = bit_end, bit_end + stride
+        with jax.named_scope("lpm_level"):
+            w32 = bit_start // 32
+            shift = 32 - stride - (bit_start % 32)
+            nib = (
+                (batch.ip_words[:, w32] >> np.uint32(shift))
+                & np.uint32((1 << stride) - 1)
+            ).astype(jnp.int32)
+            in_l = (node >= 0) & (node < tbl.shape[0])
+            alive = alive & in_l
+            r = jnp.take(tbl, node, axis=0, mode="clip")
+            w = (nib >> 5)[:, None]          # bitmap word 0..7
+            below = (np.uint32(1) << (nib & 31).astype(jnp.uint32)) - 1
+            cb = r[:, 2:10]
+            tb = r[:, 10:18]
+            pc_cb = _popcount32(cb)
+            pc_tb = _popcount32(tb)
+            prefix = jnp.sum(jnp.where(widx8 < w, pc_cb, 0), axis=1)
+            tprefix = jnp.sum(jnp.where(widx8 < w, pc_tb, 0), axis=1)
+            cw = jnp.sum(jnp.where(widx8 == w, cb, 0), axis=1)
+            tw = jnp.sum(jnp.where(widx8 == w, tb, 0), axis=1)
+            bit = (nib & 31).astype(jnp.uint32)
+            ok_t = (
+                alive
+                & (((tw >> bit) & 1) > 0)
+                & (bit_end <= cap_bits)
+            )
+            win = jnp.where(
+                ok_t, r[:, 1] + tprefix + _popcount32(tw & below), win
+            )
+            alive = alive & (((cw >> bit) & 1) > 0)
+            node = jnp.where(
+                alive,
+                (r[:, 0] + prefix + _popcount32(cw & below)).astype(jnp.int32),
+                0,
+            )
+    return in0, rows0, win
+
+
 def trie_walk(
     trie_levels, trie_targets: jax.Array, root_lut: jax.Array,
     batch: DeviceBatch
@@ -2566,9 +2654,9 @@ def trie_walk(
     node-row gather + bitmap-rank arithmetic — the child id is
     child_base + rank(nib) (implicit numbering, no pointer gather), and
     target hits record only a global index into ``trie_targets``,
-    resolved with a single gather AFTER the walk.  Statically unrolled
-    over the level count; no data-dependent control flow.  Returns the
-    target index or -1.
+    resolved with a single gather AFTER the walk (stage
+    ``lpm_resolve``).  Statically unrolled over the level count; no
+    data-dependent control flow.  Returns the target index or -1.
 
     vs the previous slot-array walk: each deep level's gather now lands
     in an array ~30x smaller (nodes, not nodes x 256 slots), which is
@@ -2580,72 +2668,13 @@ def trie_walk(
     match a v4 packet, kernel.c:207) is the boundary test
     ``bit_end <= cap_bits`` — boundaries are 16, 24, 32, 40, ... so 32
     always lands exactly on one."""
-    strides = trie_level_strides(len(trie_levels))
-    lut_size = root_lut.shape[0]
-    if_ok = (batch.ifindex >= 0) & (batch.ifindex < lut_size)
-    root = jnp.where(
-        if_ok, jnp.take(root_lut, jnp.clip(batch.ifindex, 0, lut_size - 1)), 0
-    )
-
-    # -- level 0: direct-indexed DIR-16 root --------------------------------
-    # OOB policy for every gather in the walk: indices are in-range by
-    # construction (child ranks only reach allocated nodes; dead lanes
-    # pin to 0; build_poptrie bounds the root level so e0 below cannot
-    # wrap int32), and should a future build bug break that, the lane is
-    # INVALIDATED — an explicit range mask forces it to UNDEF, i.e. XDP
-    # PASS (deterministic, never a wrong-verdict read; note this default
-    # is allow, matching the kernel's no-match semantics, kernel.c:453).
-    nib0 = (batch.ip_words[:, 0] >> np.uint32(16)).astype(jnp.int32)
-    e0 = root * 65536 + nib0
-    in0 = (e0 >= 0) & (e0 < trie_levels[0].shape[0])
-    rows0 = jnp.take(trie_levels[0], e0, axis=0, mode="clip")
-    best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1] - 1, -1)
-    alive = in0 & (rows0[:, 0] > 0)  # child ids are stored +1 (0 = none)
-    node = jnp.where(alive, rows0[:, 0] - 1, 0)
-
-    cap_bits = jnp.where(batch.kind == KIND_IPV4, 32, 128)
-    win = jnp.zeros_like(node, dtype=jnp.uint32)  # targets[0] sentinel
-    widx8 = jnp.arange(8, dtype=jnp.int32)[None, :]
-
-    bit_end = strides[0]
-    for stride, tbl in zip(strides[1:], trie_levels[1:]):
-        bit_start, bit_end = bit_end, bit_end + stride
-        w32 = bit_start // 32
-        shift = 32 - stride - (bit_start % 32)
-        nib = (
-            (batch.ip_words[:, w32] >> np.uint32(shift))
-            & np.uint32((1 << stride) - 1)
-        ).astype(jnp.int32)
-        in_l = (node >= 0) & (node < tbl.shape[0])
-        alive = alive & in_l
-        r = jnp.take(tbl, node, axis=0, mode="clip")
-        w = (nib >> 5)[:, None]          # bitmap word 0..7
-        below = (np.uint32(1) << (nib & 31).astype(jnp.uint32)) - 1
-        cb = r[:, 2:10]
-        tb = r[:, 10:18]
-        pc_cb = _popcount32(cb)
-        pc_tb = _popcount32(tb)
-        prefix = jnp.sum(jnp.where(widx8 < w, pc_cb, 0), axis=1)
-        tprefix = jnp.sum(jnp.where(widx8 < w, pc_tb, 0), axis=1)
-        cw = jnp.sum(jnp.where(widx8 == w, cb, 0), axis=1)
-        tw = jnp.sum(jnp.where(widx8 == w, tb, 0), axis=1)
-        bit = (nib & 31).astype(jnp.uint32)
-        ok_t = (
-            alive
-            & (((tw >> bit) & 1) > 0)
-            & (bit_end <= cap_bits)
-        )
-        win = jnp.where(
-            ok_t, r[:, 1] + tprefix + _popcount32(tw & below), win
-        )
-        alive = alive & (((cw >> bit) & 1) > 0)
-        node = jnp.where(
-            alive, (r[:, 0] + prefix + _popcount32(cw & below)).astype(jnp.int32), 0
-        )
-    win = win.astype(jnp.int32)
-    in_w = (win >= 0) & (win < trie_targets.shape[0])
-    tval = jnp.take(trie_targets, win, mode="clip")
-    return jnp.where(in_w & (tval > 0), tval - 1, best0)
+    in0, rows0, win = _poptrie_descend(trie_levels, root_lut, batch)
+    with jax.named_scope("lpm_resolve"):
+        win = win.astype(jnp.int32)
+        best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1] - 1, -1)
+        in_w = (win >= 0) & (win < trie_targets.shape[0])
+        tval = jnp.take(trie_targets, win, mode="clip")
+        return jnp.where(in_w & (tval > 0), tval - 1, best0)
 
 
 def lpm_trie(tables: DeviceTables, batch: DeviceBatch) -> jax.Array:
@@ -2663,65 +2692,15 @@ def trie_walk_joined(
     rows by build_joined), so target resolve + rules fetch collapse into
     ONE fat-row gather.  Returns the (B, W) joined rows; row 0 / invalid
     lanes read all-zero (-> ruleId 0 -> UNDEF)."""
-    strides = trie_level_strides(len(trie_levels))
-    lut_size = root_lut.shape[0]
-    if_ok = (batch.ifindex >= 0) & (batch.ifindex < lut_size)
-    root = jnp.where(
-        if_ok, jnp.take(root_lut, jnp.clip(batch.ifindex, 0, lut_size - 1)), 0
-    )
-    nib0 = (batch.ip_words[:, 0] >> np.uint32(16)).astype(jnp.int32)
-    e0 = root * 65536 + nib0
-    in0 = (e0 >= 0) & (e0 < trie_levels[0].shape[0])
-    rows0 = jnp.take(trie_levels[0], e0, axis=0, mode="clip")
-    best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1], 0)
-    alive = in0 & (rows0[:, 0] > 0)
-    node = jnp.where(alive, rows0[:, 0] - 1, 0)
-
-    cap_bits = jnp.where(batch.kind == KIND_IPV4, 32, 128)
-    win = jnp.zeros_like(node, dtype=jnp.uint32)
-    widx8 = jnp.arange(8, dtype=jnp.int32)[None, :]
-
-    bit_end = strides[0]
-    for stride, tbl in zip(strides[1:], trie_levels[1:]):
-        bit_start, bit_end = bit_end, bit_end + stride
-        w32 = bit_start // 32
-        shift = 32 - stride - (bit_start % 32)
-        nib = (
-            (batch.ip_words[:, w32] >> np.uint32(shift))
-            & np.uint32((1 << stride) - 1)
-        ).astype(jnp.int32)
-        in_l = (node >= 0) & (node < tbl.shape[0])
-        alive = alive & in_l
-        r = jnp.take(tbl, node, axis=0, mode="clip")
-        w = (nib >> 5)[:, None]
-        below = (np.uint32(1) << (nib & 31).astype(jnp.uint32)) - 1
-        cb = r[:, 2:10]
-        tb = r[:, 10:18]
-        pc_cb = _popcount32(cb)
-        pc_tb = _popcount32(tb)
-        prefix = jnp.sum(jnp.where(widx8 < w, pc_cb, 0), axis=1)
-        tprefix = jnp.sum(jnp.where(widx8 < w, pc_tb, 0), axis=1)
-        cw = jnp.sum(jnp.where(widx8 == w, cb, 0), axis=1)
-        tw = jnp.sum(jnp.where(widx8 == w, tb, 0), axis=1)
-        bit = (nib & 31).astype(jnp.uint32)
-        ok_t = (
-            alive
-            & (((tw >> bit) & 1) > 0)
-            & (bit_end <= cap_bits)
-        )
-        win = jnp.where(
-            ok_t, r[:, 1] + tprefix + _popcount32(tw & below), win
-        )
-        alive = alive & (((cw >> bit) & 1) > 0)
-        node = jnp.where(
-            alive, (r[:, 0] + prefix + _popcount32(cw & below)).astype(jnp.int32), 0
-        )
-    win = win.astype(jnp.int32)
-    pos = jnp.where(win > 0, win, best0)
-    in_p = (pos > 0) & (pos < joined.shape[0])
-    rows = jnp.take(joined, jnp.clip(pos, 0, joined.shape[0] - 1), axis=0,
-                    mode="clip")
-    return jnp.where(in_p[:, None], rows, 0)
+    in0, rows0, win = _poptrie_descend(trie_levels, root_lut, batch)
+    with jax.named_scope("lpm_resolve"):
+        win = win.astype(jnp.int32)
+        best0 = jnp.where(in0 & (rows0[:, 1] > 0), rows0[:, 1], 0)
+        pos = jnp.where(win > 0, win, best0)
+        in_p = (pos > 0) & (pos < joined.shape[0])
+        rows = jnp.take(joined, jnp.clip(pos, 0, joined.shape[0] - 1), axis=0,
+                        mode="clip")
+        return jnp.where(in_p[:, None], rows, 0)
 
 
 def joined_rule_rows(rows: jax.Array) -> jax.Array:
@@ -6272,12 +6251,9 @@ def _resident_step_core(
     else:
         t = tdev
         use_trie = path == "trie"
-        if use_trie and v4_only:
-            t = t._replace(
-                trie_levels=t.trie_levels[: v4_trie_depth(len(t.trie_levels))]
-            )
-        elif use_trie and depth is not None:
-            t = t._replace(trie_levels=t.trie_levels[: 1 + depth])
+        if use_trie:
+            t = t._replace(trie_levels=t.trie_levels[
+                : walk_levels(len(t.trie_levels), v4_only, depth)])
         if ov is not None:
             res, _x, _s = classify_with_overlay(t, ov, batch,
                                                 use_trie=use_trie)
@@ -6394,10 +6370,14 @@ def _resident_step_core(
 #: _resident_step_core (lpm and rule_scan inside classify and its
 #: twins), so each device op of the compiled step carries one in its
 #: op_name metadata; score, payload and sketch exist only where those
-#: tiers ride the step
+#: tiers ride the step.  The trie walks split their lpm into lpm_root
+#: (the DIR-16 root gather), lpm_level (each level's node-row gather
+#: and rank) and lpm_resolve (the target or joined-row gather); the
+#: dense compare stays in lpm alone
 RESIDENT_STAGES = (
-    "wire_decode", "flow_probe", "lpm", "rule_scan", "merge", "score",
-    "payload", "flow_insert", "pack_out", "sketch",
+    "wire_decode", "flow_probe", "lpm", "lpm_root", "lpm_level",
+    "lpm_resolve", "rule_scan", "merge", "score", "payload", "flow_insert",
+    "pack_out", "sketch",
 )
 
 #: HLO module text: a computation's header, one of its instructions (and

@@ -95,6 +95,12 @@ class ResidentPool:
             # per-pipeline-slot dispatch parity (observability: a stuck
             # slot shows as one counter flatlining)
             "slot0_dispatches": 0, "slot1_dispatches": 0,
+            # which classify the context serves, per dispatch
+            "path_dense_dispatches": 0, "path_trie_dispatches": 0,
+            "path_ctrie_dispatches": 0,
+            # the level walk (note_walk): lanes x levels the step walks,
+            # against lanes x levels the lanes' families need
+            "trie_lane_levels_walked": 0, "trie_lane_levels_needed": 0,
         }
         #: allocation count at warm-completion (mark_warm): the serving-
         #: path gate is allocs - warm_allocs == 0
@@ -122,8 +128,35 @@ class ResidentPool:
                 return self.counters["allocs"]
             return self.counters["allocs"] - self.warm_allocs
 
+    def note_walk(self, ctx: "ResidentContext", kind: np.ndarray,
+                  v4_only: bool, depth: Optional[int]) -> None:
+        """Count one dispatch of ``kind`` lanes (k admissions stacked
+        count as one): its classify path and, on the level walk, the
+        lanes x levels the step walks (all lanes walk the same static
+        depth, jaxpath.walk_levels) against the lanes x levels their
+        family needs — an IPv4 lane the levels down to the /32 boundary,
+        an IPv6 lane the table's full depth, any other lane none."""
+        from .kernels import jaxpath
+
+        counts = {f"path_{ctx.path}_dispatches": 1}
+        if ctx.path == "trie":
+            full = len(ctx.tdev.trie_levels)
+            v4 = jaxpath.v4_trie_depth(full)
+            kind = np.asarray(kind)
+            counts["trie_lane_levels_walked"] = (
+                kind.size * jaxpath.walk_levels(full, v4_only, depth))
+            counts["trie_lane_levels_needed"] = (
+                v4 * int(np.count_nonzero(kind == jaxpath.KIND_IPV4))
+                + full * int(np.count_nonzero(kind == jaxpath.KIND_IPV6)))
+        with self._lock:
+            for key, n in counts.items():
+                self.counters[key] = self.counters.get(key, 0) + n
+
     def counter_values(self) -> dict:
-        """resident_* gauges for /metrics."""
+        """resident_* gauges for /metrics, with the shape of the table
+        the context serves: its device bytes and, on the level walk, the
+        trie's level count and rows per level (level 0 is the DIR-16
+        root's slot rows, the others node rows)."""
         with self._lock:
             out = {f"resident_{k}_total": v for k, v in self.counters.items()}
             out["resident_pool_warm"] = int(self.warm_allocs is not None)
@@ -131,6 +164,17 @@ class ResidentPool:
                 self.counters["allocs"] - self.warm_allocs
                 if self.warm_allocs is not None else 0
             )
+            ctx = self._ctx
+        if ctx is not None:
+            import jax
+
+            out["resident_table_device_bytes"] = sum(
+                int(a.nbytes) for a in jax.tree.leaves(ctx.tdev))
+            if ctx.path == "trie":
+                levels = ctx.tdev.trie_levels
+                out["resident_trie_levels"] = len(levels)
+                for i, level in enumerate(levels):
+                    out[f"resident_trie_level{i}_rows"] = int(level.shape[0])
         return out
 
     # -- program context -----------------------------------------------------

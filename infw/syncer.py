@@ -53,6 +53,7 @@ from .compiler import (
 from .constants import MAX_RULES_PER_TARGET
 from .contracts import must_precede
 from .interfaces import InterfaceRegistry
+from .obs import program_span
 from .spec import IngressNodeFirewallRules
 
 log = logging.getLogger("infw.syncer")
@@ -141,8 +142,12 @@ class DataplaneSyncer:
         ebusy_retry_interval_s: float = XDP_EBUSY_RETRY_INTERVAL_S,
         analysis_mode: Optional[str] = None,
         analysis_ring=None,
+        tracer=None,
     ) -> None:
         self._factory = classifier_factory
+        # program spans of a load's phases (infw.load.*), on when the
+        # daemon runs with --trace; handed to the classifier it makes
+        self.tracer = tracer
         self._registry = registry if registry is not None else interfaces_mod.default_registry
         self._stats_poller = stats_poller
         self._checkpoint_dir = checkpoint_dir
@@ -228,7 +233,9 @@ class DataplaneSyncer:
                 # set: compilation is pure, so a CompileError (bad port
                 # string, out-of-range order...) leaves the dataplane exactly
                 # as it was — no interfaces detached, last-good rules intact.
-                desired, width = self._build_desired_content(iface_ingress_rules)
+                with program_span(self.tracer, "infw.load.compile"):
+                    desired, width = self._build_desired_content(
+                        iface_ingress_rules)
                 self._pre_sync_analysis(desired)
                 self._detach_unmanaged_interfaces(iface_ingress_rules)
                 self._attach_new_interfaces(iface_ingress_rules)
@@ -432,6 +439,8 @@ class DataplaneSyncer:
         if self._classifier is not None:
             return
         self._classifier = self._factory()
+        if self.tracer is not None and hasattr(self._classifier, "tracer"):
+            self._classifier.tracer = self.tracer
         ck = self._load_checkpoint()
         if ck is not None:
             tables, attached = ck
@@ -659,7 +668,8 @@ class DataplaneSyncer:
                     upserts = {**upserts, **self._overlay, **new_keys}
                     self._overlay = {}
                     self._overlay_compiled = None
-            self._updater.apply(upserts, deletes)
+            with program_span(self.tracer, "infw.load.compile"):
+                self._updater.apply(upserts, deletes)
             log.info(
                 "incremental table update: %d main upserts, %d main "
                 "deletes, %d overlay adds/updates (%d overlay total)",
@@ -677,13 +687,15 @@ class DataplaneSyncer:
                 log.info("compacted table: tombstones reclaimed")
                 incremental = False  # checkpoint needs the full state
         else:
-            self._updater = IncrementalTables.from_content(
-                desired, rule_width=width
-            )
+            with program_span(self.tracer, "infw.load.compile"):
+                self._updater = IncrementalTables.from_content(
+                    desired, rule_width=width
+                )
             self._overlay = {}  # full rebuild absorbs everything
             self._overlay_compiled = None
             incremental = False
-        tables = self._updater.snapshot()
+        with program_span(self.tracer, "infw.load.compile"):
+            tables = self._updater.snapshot()
         if os.environ.get("INFW_CHECK_INVARIANTS", "") not in (
             "", "0", "false", "no"
         ):
@@ -712,7 +724,8 @@ class DataplaneSyncer:
         # journal grows past its cap.
         if incremental and self._journal_pending():
             return
-        self._save_checkpoint(tables)
+        with program_span(self.tracer, "infw.load.checkpoint"):
+            self._save_checkpoint(tables)
 
     def _check_overlay_contract(self) -> None:
         """Opt-in (INFW_CHECK_INVARIANTS=1) overlay accounting contract,

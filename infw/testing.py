@@ -7,7 +7,7 @@ golden verdict vectors checked against the NumPy oracle.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -191,13 +191,16 @@ def random_tables_fast(
     width: int = 16,
     v6_fraction: float = 0.3,
     group_size: int = 8,
+    v4_prefix_lens: Sequence[int] = (0, 8, 12, 16, 20, 24, 24, 24, 28, 32),
+    v6_prefix_lens: Sequence[int] = (0, 32, 40, 48, 48, 48, 56, 64, 96, 128),
 ) -> CompiledTables:
     """Vectorized large-table generator: like random_tables but NumPy-
     vectorized end to end so 100K-1M-entry tables build in seconds (the
     scale tier of BASELINE config 3/5).  Entries cluster into groups
-    sharing a base address with realistic prefix-length mixes (v4 peaked
-    at /24, v6 at /48), so nested/sibling prefixes stress longest-match
-    tie-breaks exactly like the per-entry generator."""
+    sharing a base address, prefix lengths drawn uniformly from the
+    given lists (by default v4 peaked at /24, v6 at /48), so
+    nested/sibling prefixes stress longest-match tie-breaks exactly like
+    the per-entry generator."""
     content: Dict[LpmKey, np.ndarray] = {}
     seen = set()
     while len(content) < n_entries:
@@ -214,8 +217,8 @@ def random_tables_fast(
         rows_i = np.arange(n)[perturb]
         ip[rows_i, pos[perturb]] = val[perturb]
 
-        v4_lens = np.array([0, 8, 12, 16, 20, 24, 24, 24, 28, 32])
-        v6_lens = np.array([0, 32, 40, 48, 48, 48, 56, 64, 96, 128])
+        v4_lens = np.asarray(v4_prefix_lens)
+        v6_lens = np.asarray(v6_prefix_lens)
         mask_len = np.where(
             is_v6,
             v6_lens[rng.integers(0, len(v6_lens), n)],

@@ -13,6 +13,7 @@ worker imports every test file.  The persistent compilation cache is off
 around the compiles, since an entry written for a described chip cannot
 be read back without one.
 """
+import contextlib
 import os
 import re
 
@@ -162,6 +163,72 @@ def test_resident_step_flow_stages_lane_dense(one_chip):
         if stages[m.group(1)] in ("flow_probe", "flow_insert"):
             assert [d for d in dims if d > 1] != sorted([cfg.ways, b]), (
                 m.group(0)[:200])
+
+
+def _resident_step_args(b: int, tdev, sharding):
+    """The resident step's operands at the benchmark cells' flow geometry
+    (a 16,384-slot 4-way table) and ``b``-packet 7-word records."""
+    cfg = FlowConfig.make(entries=16384)
+    tier = FlowTier(cfg)
+    lane = jax.ShapeDtypeStruct((b,), jnp.int32)
+    args = (tier._flow, tier._gens_dev, tier._pages_dev, np.int32(0), tdev,
+            jax.ShapeDtypeStruct((b, 7), jnp.uint32), lane, lane,
+            tier._max_age_dev)
+    return cfg, shapes(args, sharding)
+
+
+@pytest.mark.parametrize("tail", ["targets", "joined"])
+def test_resident_trie_step_at_blocklist_shapes(one_chip, tail):
+    """The resident step on the trie path at the blocklist-100k cell's
+    shapes: 65,536-packet records, a DIR-16 root and 4 node levels (/48
+    deepest), 8 rule slots an entry; with the target-index tail that
+    table takes (its leaf-pushed targets trip the joined duplication
+    gate) and with the joined-row tail (3 + 5 x 8 words).  Every
+    instruction that does work maps to a stage, the walk to its own."""
+    from test_trie_walk import WALK_STAGES, routed_tables
+
+    tdev = jaxpath.device_tables(routed_tables(), pad=True)
+    assert len(tdev.trie_levels) == 5
+    if tail == "joined":
+        tdev = tdev._replace(joined=jax.ShapeDtypeStruct(
+            (tdev.trie_targets.shape[0], 3 + 5 * 8), jnp.uint16))
+    cfg, args = _resident_step_args(65536, tdev, one_chip)
+    fn = jaxpath.jitted_resident_step(cfg.entries, cfg.ways, "trie")
+    text = compile_for(fn, *args).as_text()
+    stages = jaxpath.hlo_stage_map(text)
+    assert WALK_STAGES <= set(stages.values())
+    structural = ("parameter", "constant", "tuple", "get-tuple-element")
+    opcode = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+.*?\s([a-z][\w\-]*)\(")
+    for m in filter(None, map(opcode.match, text.splitlines())):
+        if m.group(2) not in structural:
+            assert stages[m.group(1)] != "other", m.group(0)[:200]
+
+
+def test_refcap_dense_step_scopes_add_metadata_only(one_chip, monkeypatch):
+    """The resident step at the refcap-1k cell's shapes (dense path, 1,024
+    entries x 100 rule slots, 65,536-packet records) compiles for the
+    chip to the same optimized HLO with its stage scopes as without
+    them, apart from instruction metadata."""
+    from test_stages import strip_metadata
+
+    tables = testing.random_tables_fast(
+        np.random.default_rng(101), n_entries=1024, width=100,
+        v6_fraction=0.5, ifindexes=(2, 3, 4),
+    )
+    cfg, args = _resident_step_args(
+        65536, jaxpath.device_tables(tables, pad=True), one_chip)
+
+    def compiled_text():
+        fn = jaxpath.jitted_resident_step.__wrapped__(cfg.entries, cfg.ways,
+                                                      "dense")
+        return compile_for(fn, *args).as_text()
+
+    scoped = compiled_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda _name: contextlib.nullcontext())
+    plain = compiled_text()
+    assert "/rule_scan/" in scoped and "/rule_scan/" not in plain
+    assert strip_metadata(scoped) == strip_metadata(plain)
 
 
 def test_mesh_walk_on_four_chips(topo, cache_off):

@@ -78,11 +78,17 @@ ENTRY %main (p: u32[8]) -> (u32[8], u32[8]) {
     }
 
 
-@pytest.mark.parametrize("path,deep,k", [("dense", False, 0),
-                                         ("trie", True, 0),
-                                         ("dense", False, 2)],
+#: the LPM's stages: the dense compare is lpm alone, the trie walk's ops
+#: all sit in the three stages inside it
+DENSE_LPM = {"lpm"}
+WALK_LPM = {"lpm_root", "lpm_level", "lpm_resolve"}
+
+
+@pytest.mark.parametrize("path,deep,k,lpm", [("dense", False, 0, DENSE_LPM),
+                                             ("trie", True, 0, WALK_LPM),
+                                             ("dense", False, 2, DENSE_LPM)],
                          ids=["dense", "trie", "superbatch"])
-def test_cpu_resident_step_maps_every_instruction(path, deep, k):
+def test_cpu_resident_step_maps_every_instruction(path, deep, k, lpm):
     cfg, args = resident_args(256, deep, k)
     factory = (jaxpath.jitted_resident_superbatch if k
                else jaxpath.jitted_resident_step)
@@ -91,7 +97,8 @@ def test_cpu_resident_step_maps_every_instruction(path, deep, k):
     assert text.startswith(f"HloModule {name},")
     assert unnamed(text) == []
     stages = set(jaxpath.hlo_stage_map(text).values())
-    assert {"lpm", "rule_scan", "flow_probe", "flow_insert"} <= stages
+    assert lpm | {"rule_scan", "flow_probe", "flow_insert"} <= stages
+    assert not (DENSE_LPM | WALK_LPM) - lpm & stages
     assert stages <= set(jaxpath.RESIDENT_STAGES) | {"other"}
 
 
@@ -113,3 +120,23 @@ def test_scopes_add_metadata_only(monkeypatch):
     assert "metadata=" not in stripped and "FileNames" not in stripped
     assert len(opcodes(stripped)) == len(opcodes(scoped)) > 100
     assert stripped == strip_metadata(plain)
+
+
+def test_walk_scopes_add_metadata_only(monkeypatch):
+    """The trie step's walk stages (lpm_root, lpm_level, lpm_resolve
+    inside lpm) change its metadata and nothing else."""
+    cfg, args = resident_args(256, True)
+
+    def compiled_text():
+        fn = jaxpath.jitted_resident_step.__wrapped__(cfg.entries, cfg.ways,
+                                                      "trie")
+        return fn.lower(*args).compile().as_text()
+
+    scoped = compiled_text()
+    real = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: (
+        contextlib.nullcontext() if name.startswith("lpm_") else real(name)))
+    plain = compiled_text()
+    assert "/lpm/lpm_level/" in scoped and "/lpm_level/" not in plain
+    assert "/lpm/" in plain
+    assert strip_metadata(scoped) == strip_metadata(plain)
