@@ -473,18 +473,12 @@ class FlowTier:
         #: FlowEvictRecord on the obs ring)
         self.on_evict: Optional[Callable] = None
         C = config.capacity
-        # slot-minor on the device (jaxpath.FlowTable)
-        host = {
-            "keys": np.zeros((FLOW_KEY_WORDS, C), np.uint32),
-            "vg": np.zeros((2, C), np.int32),
-            "se": np.zeros((2, C), np.int32),
-            "cnt": np.zeros((3, C), np.int32),
-        }
         put = lambda name, a: jax.device_put(
             jnp.asarray(a), self._shardings.get(name, device)
         )
+        # one packed slot-minor matrix on the device (jaxpath.FlowTable)
         self._flow = jaxpath.FlowTable(
-            **{k: put(k, v) for k, v in host.items()}
+            state=put("state", np.zeros((jaxpath.FLOW_ROWS, C), np.int32))
         )
         self._gens_host = np.zeros(config.max_tenants, np.int32)
         self._pages_host = np.full(config.max_tenants, -1, np.int32)
@@ -1006,8 +1000,7 @@ class FlowTier:
         with self._lock:
             cutoff = self._epoch - h
             cdev = self._put(np.int32(cutoff))
-            se, aged = jaxpath.jitted_flow_age()(self._flow.se, cdev)
-            self._flow = self._flow._replace(se=se)
+            self._flow, aged = jaxpath.jitted_flow_age()(self._flow, cdev)
             if self.model is not None:
                 self.model.age(cutoff)
         aged = int(np.asarray(aged))
@@ -1015,21 +1008,16 @@ class FlowTier:
         return aged
 
     def reset(self) -> None:
-        """Drop every resident flow (fresh zero columns) — the bench's
+        """Drop every resident flow (a fresh zero state) — the bench's
         per-measured-pass cold start; generations and pages persist."""
         import jax
         import jax.numpy as jnp
 
         with self._lock:
-            flow = self._flow
-            zeros = {
-                k: jax.device_put(
-                    jnp.zeros_like(getattr(flow, k)),
-                    self._shardings.get(k, self._device),
-                )
-                for k in flow._fields
-            }
-            self._flow = flow._replace(**zeros)
+            self._flow = self._flow._replace(state=jax.device_put(
+                jnp.zeros_like(self._flow.state),
+                self._shardings.get("state", self._device),
+            ))
             if self.model is not None:
                 m = HostFlowModel(self.config)
                 m.gens = self.model.gens
@@ -1045,7 +1033,7 @@ class FlowTier:
         # occupancy program reads it ("Array has been deleted")
         with self._lock:
             return int(np.asarray(
-                jaxpath.jitted_flow_occupancy()(self._flow.se)
+                jaxpath.jitted_flow_occupancy()(self._flow)
             ))
 
     @property
@@ -1054,17 +1042,21 @@ class FlowTier:
             return self._epoch
 
     def flow_columns(self) -> Dict[str, np.ndarray]:
-        """Host copies of the device columns in the (C, k) slot-major
-        form HostFlowModel.columns() keeps (the model-checker compare
-        side).  Materialized INSIDE the lock: the resident loop donates
-        these buffers per admission, so an off-lock snapshot could be
-        deleted by a concurrent dispatch mid-read."""
+        """Host copies of the device state's fields in the (C, k)
+        slot-major form HostFlowModel.columns() keeps (the model-checker
+        compare side).  Materialized INSIDE the lock: the resident loop
+        donates the state per admission, so an off-lock snapshot could
+        be deleted by a concurrent dispatch mid-read."""
+        from .kernels import jaxpath
+
         with self._lock:
-            flow = self._flow
-            return {
-                k: np.ascontiguousarray(np.asarray(getattr(flow, k)).T)
-                for k in flow._fields
-            }
+            state = np.asarray(self._flow.state)
+        cols = {
+            k: np.ascontiguousarray(state[lo:hi].T)
+            for k, (lo, hi) in jaxpath.FLOW_FIELDS.items()
+        }
+        cols["keys"] = cols["keys"].view(np.uint32)
+        return cols
 
     def counter_values(self) -> Dict[str, int]:
         """flow_* counters + occupancy gauge for /metrics."""

@@ -599,7 +599,7 @@ def _build_flow_insert(b: int):
 
 
 def _resident_operands(b: int):
-    """Fresh flow columns + steering scalars for one resident trace."""
+    """Fresh flow state + steering scalars for one resident trace."""
     import jax
 
     from ..flow import FlowConfig
@@ -608,10 +608,7 @@ def _resident_operands(b: int):
     cfg = FlowConfig.make(entries=512)
     C = cfg.capacity
     flow = jaxpath.FlowTable(
-        keys=jax.device_put(np.zeros((8, C), np.uint32)),
-        vg=jax.device_put(np.zeros((2, C), np.int32)),
-        se=jax.device_put(np.zeros((2, C), np.int32)),
-        cnt=jax.device_put(np.zeros((3, C), np.int32)),
+        state=jax.device_put(np.zeros((jaxpath.FLOW_ROWS, C), np.int32)),
     )
     gens = jax.device_put(np.zeros(1, np.int32))
     pages = jax.device_put(np.zeros(1, np.int32))

@@ -5797,12 +5797,12 @@ class ArenaAllocator:
 # fields (tenant, ifindex, source IP words, proto, dst_port, icmp
 # type/code, kind, l4_ok), so a hit can serve the cached res16 verdict
 # with bit-identical semantics to the stateless path — the invariant the
-# flow statecheck configs and bench_flow gate on.  Layout is columnar
-# (one tensor per field, C = pages * slab_entries rows) with per-tenant
-# SLABS: the per-packet tenant column steers the slot range exactly the
-# way the arena page table steers classification, and the key embeds the
-# tenant id so a paging bug can never serve one tenant's verdict to
-# another (isolation is key-level, not just slab-level).
+# flow statecheck configs and bench_flow gate on.  Layout is one packed
+# matrix (a row window per field, C = pages * slab_entries slots) with
+# per-tenant SLABS: the per-packet tenant column steers the slot range
+# exactly the way the arena page table steers classification, and the
+# key embeds the tenant id so a paging bug can never serve one tenant's
+# verdict to another (isolation is key-level, not just slab-level).
 #
 # Mutations are all deterministic scatter forms (add / max / min / set at
 # per-slot-unique winner lanes), so the numpy host model
@@ -5836,20 +5836,35 @@ FLOW_FIN = 3
 FLOW_KEY_WORDS = 8
 
 
-class FlowTable(NamedTuple):
-    """Device-resident flow columns (C = pages * slab_entries slots),
-    stored slot-minor, (k, C): the probe's way-gather along the slot
-    axis yields (k, W, B) planes with the batch on the minor axis, the
-    layout the way compares and reductions take.  Mutable state is packed
-    into THREE narrow matrices so the probe's in-kernel updates are 3
-    scatter ops, not 6 — scatter op count is what the probe's cost
-    scales with.  The host side (FlowTier.flow_columns, HostFlowModel)
-    keeps the (C, k) slot-major form."""
+#: rows of the packed flow-state matrix (FlowTable.state): the key
+#: words [tenant, ifindex, ip0..3, m0, m1] in rows 0-7, then the
+#: mutable fields, each a row window, then one pad row that stays 0
+FLOW_SE = 8    # [FLOW_* state, last-seen epoch]
+FLOW_VG = 10   # [cached res16 verdict, tenant gen]
+FLOW_CNT = 12  # [pkts, sum(len>>8), sum(len&0xFF)]
+FLOW_ROWS = 16
 
-    keys: jax.Array  # (8, C) uint32 [tenant, ifindex, ip0..3, m0, m1]
-    vg: jax.Array    # (2, C) int32 [cached res16 verdict, tenant gen]
-    se: jax.Array    # (2, C) int32 [FLOW_* state, last-seen epoch]
-    cnt: jax.Array   # (3, C) int32 [pkts, sum(len>>8), sum(len&0xFF)]
+#: field -> (first row, end row) of the packed matrix; the host side
+#: (FlowTier.flow_columns, HostFlowModel) keeps one (C, k) column each
+FLOW_FIELDS = {
+    "keys": (0, FLOW_KEY_WORDS),
+    "se": (FLOW_SE, FLOW_SE + 2),
+    "vg": (FLOW_VG, FLOW_VG + 2),
+    "cnt": (FLOW_CNT, FLOW_CNT + 3),
+}
+
+
+class FlowTable(NamedTuple):
+    """Device-resident flow state (C = pages * slab_entries slots): ONE
+    (FLOW_ROWS, C) int32 matrix, stored slot-minor, with the row map of
+    FLOW_FIELDS (the key words are bitcast uint32).  A probe or insert
+    reads a lane's candidate slots with one way-gather, yielding
+    (FLOW_ROWS, W, B) planes with the batch on the minor axis, and
+    writes a slot's whole column with one scatter: a TPU gather or
+    scatter is paid per index far more than per row it moves, so the
+    op count is what the flow tier's cost scales with."""
+
+    state: jax.Array  # (FLOW_ROWS, C) int32
 
 
 def flow_key_words(batch: DeviceBatch, tenant: jax.Array) -> jax.Array:
@@ -5923,6 +5938,23 @@ def unpack_bits32_host(words: np.ndarray, b: int) -> np.ndarray:
     return bits.reshape(-1)[:b].astype(bool)
 
 
+def _flow_rows(lo: int, vals: jax.Array, fill: int) -> jax.Array:
+    """A (FLOW_ROWS, B) scatter update: ``vals`` (k, B) in rows lo..lo+k
+    and ``fill``, the combiner's identity, in every other row.  The TPU
+    scatters a slot's whole column natively, but expands a window of
+    some of its rows into a loop over the indices."""
+    k = vals.shape[0]
+    return jnp.pad(vals.astype(jnp.int32), ((lo, FLOW_ROWS - lo - k), (0, 0)),
+                   constant_values=fill)
+
+
+def _flow_counts(ln: jax.Array) -> jax.Array:
+    """A packet's counter contributions [1, len>>8, len&0xFF] in the
+    counter rows of a (FLOW_ROWS, B) add-scatter update."""
+    return _flow_rows(FLOW_CNT, jnp.stack(
+        [jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF]), 0)
+
+
 def _flow_probe_parts(
     flow: FlowTable, gens: jax.Array, page_table: jax.Array,
     batch: DeviceBatch, tenant: jax.Array, tflags: jax.Array,
@@ -5930,7 +5962,7 @@ def _flow_probe_parts(
     *, slab_entries: int, ways: int,
 ):
     """The shared probe body -> (served u32 verdicts, hit mask, stale
-    mask, updated mutable columns) — un-fused so the resident serving
+    mask, updated FlowTable) — un-fused so the resident serving
     step (jitted_resident_step) can compose the probe with the stateless
     classify and the miss insert inside ONE device program; the classic
     probe dispatch (_flow_probe_core) packs these parts into its fused
@@ -5945,15 +5977,16 @@ def _flow_probe_parts(
     Per-ruleId statistics for the served lanes derive HOST-side from the
     returned res16 + pkt_len (the wire8 readback contract), so the probe
     ships no stats tensor."""
-    C = flow.se.shape[1]
+    C = flow.state.shape[1]
     page = _arena_pages(page_table, tenant)
     keyw = flow_key_words(batch, tenant)
     is_ip = (batch.kind == KIND_IPV4) | (batch.kind == KIND_IPV6)
     elig = is_ip & (batch.l4_ok != 0) & (page >= 0)
     cand = _flow_slots(keyw, page, slab_entries=slab_entries, ways=ways)
-    ek = jnp.take(flow.keys, cand, axis=1, mode="clip")   # (8, W, B)
-    ese = jnp.take(flow.se, cand, axis=1, mode="clip")    # (2, W, B)
-    evg = jnp.take(flow.vg, cand, axis=1, mode="clip")
+    rows = jnp.take(flow.state, cand, axis=1, mode="clip")  # (16, W, B)
+    ek = jax.lax.bitcast_convert_type(rows[:FLOW_KEY_WORDS], jnp.uint32)
+    ese = rows[FLOW_SE : FLOW_SE + 2]
+    evg = rows[FLOW_VG : FLOW_VG + 2]
     match = jnp.all(ek == keyw[:, None, :], axis=0) & elig[None, :]
     live = ese[0] >= FLOW_EST
     mygen = jnp.take(gens, jnp.clip(tenant, 0, gens.shape[0] - 1),
@@ -5975,30 +6008,29 @@ def _flow_probe_parts(
         hit, jnp.sum(jnp.where(at_first, evg[0], 0), axis=0), 0
     ).astype(jnp.uint32)
 
-    ln = batch.pkt_len
-    cnt = flow.cnt.at[:, slot].add(
-        jnp.stack([jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF]),
-        mode="drop",
-    )
+    state = flow.state.at[:, slot].add(_flow_counts(batch.pkt_len),
+                                       mode="drop")
     is_tcp = batch.proto == IPPROTO_TCP
     fin = is_tcp & ((tflags & TCP_FIN) != 0)
     rst = is_tcp & ((tflags & TCP_RST) != 0)
     # ONE max-scatter carries both the FIN half-close transition and the
     # last-seen refresh (epoch_now >= any stored epoch by monotonicity);
     # one min-scatter applies RST teardown
-    big = jnp.int32(np.iinfo(np.int32).max)
-    se = flow.se.at[:, slot].max(
-        jnp.stack([
+    i32 = np.iinfo(np.int32)
+    state = state.at[:, slot].max(
+        _flow_rows(FLOW_SE, jnp.stack([
             jnp.where(hit & fin, FLOW_FIN, -1).astype(jnp.int32),
             jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32),
-        ]),
+        ]), i32.min),
         mode="drop",
     )
-    se = se.at[:, jnp.where(hit & rst, slot, C)].min(
-        jnp.stack([jnp.full_like(slot, FLOW_EMPTY), jnp.full_like(slot, big)]),
+    state = state.at[:, jnp.where(hit & rst, slot, C)].min(
+        _flow_rows(FLOW_SE, jnp.stack([
+            jnp.full_like(slot, FLOW_EMPTY), jnp.full_like(slot, i32.max),
+        ]), i32.max),
         mode="drop",
     )
-    return served, hit, stale, flow._replace(se=se, cnt=cnt)
+    return served, hit, stale, FlowTable(state=state)
 
 
 def _flow_probe_core(
@@ -6008,7 +6040,7 @@ def _flow_probe_core(
     *, slab_entries: int, ways: int,
 ):
     """The classic probe dispatch: _flow_probe_parts packed into the
-    fused readback buffer -> (fused output, updated mutable columns)."""
+    fused readback buffer -> (fused output, updated FlowTable)."""
     served, hit, stale, updated = _flow_probe_parts(
         flow, gens, page_table, batch, tenant, tflags, epoch_now,
         max_age, slab_entries=slab_entries, ways=ways,
@@ -6077,7 +6109,7 @@ def _flow_insert_core(
     step instead masks the hit lanes out.  Eligible-lane identity and
     relative order are the same either way, so winner selection and the
     counter segment sums stay bit-identical."""
-    C = flow.se.shape[1]
+    C = flow.state.shape[1]
     page = _arena_pages(page_table, tenant)
     keyw = flow_key_words(batch, tenant)
     is_ip = (batch.kind == KIND_IPV4) | (batch.kind == KIND_IPV6)
@@ -6090,10 +6122,10 @@ def _flow_insert_core(
     if lane_ok is not None:
         elig = elig & lane_ok
     cand = _flow_slots(keyw, page, slab_entries=slab_entries, ways=ways)
-    ek = jnp.take(flow.keys, cand, axis=1, mode="clip")
-    ese = jnp.take(flow.se, cand, axis=1, mode="clip")
-    est = ese[0]
-    eep = ese[1]
+    rows = jnp.take(flow.state, cand, axis=1, mode="clip")  # (16, W, B)
+    ek = jax.lax.bitcast_convert_type(rows[:FLOW_KEY_WORDS], jnp.uint32)
+    est = rows[FLOW_SE]
+    eep = rows[FLOW_SE + 1]
     match_w = jnp.all(ek == keyw[:, None, :], axis=0) & (est > 0)
     empty_w = est == 0
     W = ways
@@ -6109,21 +6141,20 @@ def _flow_insert_core(
     matched = m_first < W
     old_state = jnp.sum(jnp.where(at_way, est, 0), axis=0)
 
-    # last eligible lane per slot wins the .set() writes
+    # per slot, in the state's row map (column C takes the ineligible
+    # lanes): row 0 the last eligible lane, which wins the .set()
+    # writes; the counter rows the seeds, batch contributions summed
+    # over ALL eligible lanes (the probe's counter update, reused)
     lane = jnp.arange(slot.shape[0], dtype=jnp.int32)
     idx_e = jnp.where(elig, slot, C)
-    winner = jnp.full(C + 1, -1, jnp.int32).at[idx_e].max(lane, mode="drop")
-    win = elig & (
-        jnp.take(winner, jnp.clip(slot, 0, C), mode="clip") == lane
-    )
+    acc = jnp.concatenate([jnp.full((1, C + 1), -1, jnp.int32),
+                           jnp.zeros((FLOW_ROWS - 1, C + 1), jnp.int32)])
+    acc = acc.at[:, idx_e].max(
+        _flow_rows(0, lane[None], np.iinfo(np.int32).min), mode="drop")
+    acc = acc.at[:, idx_e].add(_flow_counts(batch.pkt_len), mode="drop")
+    at_slot = jnp.take(acc, jnp.clip(slot, 0, C), axis=1, mode="clip")
+    win = elig & (at_slot[0] == lane)
     idx_w = jnp.where(win, slot, C)
-
-    # per-slot batch contributions (counter seeds) over ALL eligible lanes
-    ln = batch.pkt_len
-    seeds = jnp.zeros((3, C), jnp.int32).at[:, idx_e].add(
-        jnp.stack([jnp.ones_like(ln), (ln >> 8) & 0xFFFFFF, ln & 0xFF]),
-        mode="drop",
-    )
 
     state_val = jnp.where(
         fin, FLOW_FIN,
@@ -6131,20 +6162,20 @@ def _flow_insert_core(
     ).astype(jnp.int32)
     mygen = jnp.take(gens, jnp.clip(tenant, 0, gens.shape[0] - 1),
                      mode="clip")
-    keys = flow.keys.at[:, idx_w].set(keyw, mode="drop")
-    vg = flow.vg.at[:, idx_w].set(
-        jnp.stack([(verdict16.astype(jnp.int32)) & 0xFFFF, mygen]),
-        mode="drop",
-    )
-    se = flow.se.at[:, idx_w].set(
+    # every row of the winner's slot in one .set: the key, se and vg
+    # rows from this lane, the counter rows (and the pad) from the seeds
+    head = jnp.concatenate([
+        jax.lax.bitcast_convert_type(keyw, jnp.int32),
         jnp.stack([
             state_val,
             jnp.broadcast_to(epoch_now, slot.shape).astype(jnp.int32),
+            (verdict16.astype(jnp.int32)) & 0xFFFF,
+            mygen,
         ]),
-        mode="drop",
-    )
-    cnt = flow.cnt.at[:, idx_w].set(
-        jnp.take(seeds, jnp.clip(slot, 0, C - 1), axis=1, mode="clip"),
+    ])
+    state = flow.state.at[:, idx_w].set(
+        jnp.where((jnp.arange(FLOW_ROWS) < FLOW_CNT)[:, None],
+                  _flow_rows(0, head, 0), at_slot),
         mode="drop",
     )
 
@@ -6158,7 +6189,7 @@ def _flow_insert_core(
         jnp.sum(promote.astype(jnp.int32)),
         jnp.int32(0),
     ])
-    return FlowTable(keys=keys, vg=vg, se=se, cnt=cnt), counts
+    return FlowTable(state=state), counts
 
 
 @functools.lru_cache(maxsize=None)
@@ -6175,13 +6206,15 @@ def jitted_flow_insert(slab_entries: int, ways: int):
 
 @functools.lru_cache(maxsize=None)
 def jitted_flow_age():
-    """Epoch-based age sweep over the (state, epoch) matrix: entries
-    last seen strictly before ``cutoff`` free their slot.  Returns
-    (new se, aged count)."""
-    def f(se, cutoff):
-        expire = (se[0] > 0) & (se[1] < cutoff)
+    """Epoch-based age sweep over the flow state: entries last seen
+    strictly before ``cutoff`` free their slot.  Returns (new
+    FlowTable, aged count)."""
+    def f(flow, cutoff):
+        st, ep = flow.state[FLOW_SE], flow.state[FLOW_SE + 1]
+        expire = (st > 0) & (ep < cutoff)
         return (
-            se.at[0].set(jnp.where(expire, FLOW_EMPTY, se[0])),
+            FlowTable(state=flow.state.at[FLOW_SE].set(
+                jnp.where(expire, FLOW_EMPTY, st))),
             jnp.sum(expire.astype(jnp.int32)),
         )
 
@@ -6190,7 +6223,8 @@ def jitted_flow_age():
 
 @functools.lru_cache(maxsize=None)
 def jitted_flow_occupancy():
-    return jax.jit(lambda se: jnp.sum((se[0] > 0).astype(jnp.int32)))
+    return jax.jit(
+        lambda flow: jnp.sum((flow.state[FLOW_SE] > 0).astype(jnp.int32)))
 
 
 # === resident serving step (zero-copy donated-buffer loop, ISSUE-12) =========
@@ -6199,9 +6233,9 @@ def jitted_flow_occupancy():
 # stateless classify + verdict merge + device stats + miss insert — the
 # in-program composition of the probe-then-classify multi-dispatch plan
 # (backend/tpu.py _launch_flow), which pays three launches and two
-# blocking host round-trips per admission.  The mutable flow columns and
+# blocking host round-trips per admission.  The flow state matrix and
 # the epoch scalar are DONATED (jax.jit donate_argnums input-output
-# aliasing): XLA writes the updated columns back into the very buffers
+# aliasing): XLA writes the updated state back into the very buffers
 # the previous dispatch produced, so the steady-state loop performs zero
 # flow-state device allocations and the epoch never crosses the link —
 # the program increments it on device and hands the aliased buffer to
@@ -6521,8 +6555,8 @@ def split_resident_payload_outputs(arr: np.ndarray, b: int,
     return head + (pay_hit, pay_rw)
 
 
-#: donated operand positions of the resident step — the flow column
-#: pytree and the device epoch scalar; declared here so the entrypoint
+#: donated operand positions of the resident step — the flow state
+#: (FlowTable) and the device epoch scalar; declared here so the entrypoint
 #: registry and the jaxcheck donation lint share one source of truth
 RESIDENT_DONATE_ARGNUMS = (0, 3)
 

@@ -749,19 +749,16 @@ def arena_replicated(mesh: Mesh) -> NamedSharding:
 # --- stateful flow tier (ISSUE-11) ------------------------------------------
 #
 # The flow slab family's partition rules, declared once like the arena
-# pools: flow columns slot-shard over "rules" when the slot count divides
+# pools: the flow state slot-shards over "rules" when the slot count divides
 # the axis (capacity scales with it; the probe/insert gathers and
 # scatters engage GSPMD under the SAME jitted factories the single chip
 # uses), while the small per-tenant steering state (generation vector,
 # flow page table) replicates like the arena page table.
 
 FLOW_PARTITION_RULES = {
-    # the slot-minor FlowTable columns (jaxpath.FlowTable: keys / vg /
-    # se / cnt), slots on the minor axis
-    "keys": P(None, "rules"),
-    "vg": P(None, "rules"),
-    "se": P(None, "rules"),
-    "cnt": P(None, "rules"),
+    # the packed slot-minor flow state (jaxpath.FlowTable), slots on
+    # the minor axis
+    "state": P(None, "rules"),
     # per-tenant steering state: replicated like the arena page table
     "gens": P(),
     "page_table": P(),

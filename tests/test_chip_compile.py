@@ -134,16 +134,12 @@ def test_resident_step(one_chip):
             assert stages[m.group(1)] != "other", m.group(0)[:200]
 
 
-def test_resident_step_flow_stages_lane_dense(one_chip):
-    """The resident step at the benchmark cell's shapes (dense path,
-    65,536-packet records, a 16,384-slot 4-way flow table, flow columns
-    donated): no copy in the flow_probe or flow_insert stage has the
-    batch and the ways as its only dimensions above one.  Stored
-    slot-major, (C, k), each way-gathered column came out as (B, W, 1)
-    with the ways on the lanes and took such a relayout copy before its
-    consumers.  The 2-word state and verdict gathers still leave the
-    gather as (W * B, 2) rows and are relayouted to (2, W, B) planes;
-    this test does not pin those."""
+@pytest.fixture(scope="module")
+def flow_step(one_chip):
+    """(flow config, optimized HLO text, {instruction: stage}) of the
+    resident step at the benchmark cell's shapes: dense path,
+    65,536-packet records, a 16,384-slot 4-way flow table, flow state
+    donated."""
     b = 65536
     cfg = FlowConfig.make(entries=16384)
     tier = FlowTier(cfg)
@@ -154,7 +150,19 @@ def test_resident_step_flow_stages_lane_dense(one_chip):
             jax.ShapeDtypeStruct((b, 7), jnp.uint32), lane, lane,
             tier._max_age_dev)
     text = compile_for(fn, *shapes(args, one_chip)).as_text()
-    stages = jaxpath.hlo_stage_map(text)
+    return cfg, text, jaxpath.hlo_stage_map(text)
+
+
+def test_resident_step_flow_stages_lane_dense(flow_step):
+    """The resident step at the benchmark cell's shapes: no copy in the
+    flow_probe or flow_insert stage has the batch and the ways as its
+    only dimensions above one.  Stored slot-major, (C, k), each
+    way-gathered column came out as (B, W, 1) with the ways on the lanes
+    and took such a relayout copy before its consumers.  The packed
+    state's way-gather still leaves the gather as (W * B, 16) rows and
+    is relayouted to (16, W, B) planes; this test does not pin that."""
+    cfg, text, stages = flow_step
+    b = 65536
     assert {"flow_probe", "flow_insert"} <= set(stages.values())
     copy = re.compile(
         r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+\w+\[([\d,]*)\]\S*\s+copy\(")
@@ -163,6 +171,21 @@ def test_resident_step_flow_stages_lane_dense(one_chip):
         if stages[m.group(1)] in ("flow_probe", "flow_insert"):
             assert [d for d in dims if d > 1] != sorted([cfg.ways, b]), (
                 m.group(0)[:200])
+
+
+def test_resident_step_flow_tier_native_gathers(flow_step):
+    """At the same shapes the chip's compiler keeps the flow tier's nine
+    gathers and scatters as gathers and scatters of the packed matrix's
+    whole columns.  It would expand one that moves a window of some of
+    a column's rows into a loop over its 65,536 or 262,144 indices."""
+    _cfg, text, stages = flow_step
+    opcode = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+.*?\s([a-z][\w\-]*)\(")
+    flow = [m.group(2)
+            for m in filter(None, map(opcode.match, text.splitlines()))
+            if stages[m.group(1)] in ("flow_probe", "flow_insert")]
+    assert sorted(op for op in flow if op in ("gather", "scatter")) == (
+        ["gather"] * 3 + ["scatter"] * 6)
+    assert "while" not in flow and "dynamic-update-slice" not in flow
 
 
 def _resident_step_args(b: int, tdev, sharding):

@@ -3,7 +3,7 @@
 Covers: bit-identity of the ONE-fused-program-per-admission dispatch
 (decode + flow probe + stateless classify + merge + stats + miss
 insert) vs the multi-dispatch flow plan AND the CPU oracle — verdicts,
-statistics and all four donated flow columns; donation aliasing
+statistics and the donated flow state; donation aliasing
 discipline (back-to-back dispatches must not corrupt earlier unread
 outputs, incl. under the scheduler's ping-pong staging and on the
 8-virtual-device mesh); table-patch staleness (the pool context
@@ -142,12 +142,12 @@ def test_resident_tcp_flags_and_v4_compact(shared):
         assert np.array_equal(a[k], b[k]), f"flow column {k} diverged"
 
 
-def test_resident_slot_minor_columns_over_flow_events():
-    """The device keeps the flow columns slot-minor; resident steps over
-    lanes that hit, go stale, FIN, RST, evict and promote leave
-    flow_columns() equal to the host model's (C, k) columns and to the
-    classic plan's, with the classic plan's verdicts, statistics and
-    flow counters out of the fused readbacks."""
+def _flow_events_pair():
+    """(resident, classic) classifiers with shadow host models, after
+    resident steps and classic dispatches over lanes that hit, go stale,
+    FIN, RST, evict and promote; each chunk's verdicts, xdp and
+    statistics checked against the oracle and each other, and the flow
+    counters of the two equal and above 0."""
     tabs = _tables(v6=0.3)
     res, multi = _pair(tabs, flow_track_model=True)
     batch, _meta = testing.flow_trace_batch(
@@ -174,6 +174,16 @@ def test_resident_slot_minor_columns_over_flow_events():
     for k in ("hits", "misses", "inserts", "evictions", "promotes",
               "stale_rejects"):
         assert got[k] == want[k] > 0, (k, got[k], want[k])
+    return res, multi
+
+
+def test_resident_slot_minor_columns_over_flow_events():
+    """The device keeps the flow state slot-minor in one packed matrix;
+    resident steps over lanes that hit, go stale, FIN, RST, evict and
+    promote leave flow_columns() equal to the host model's (C, k)
+    columns and to the classic plan's, with the classic plan's verdicts,
+    statistics and flow counters out of the fused readbacks."""
+    res, multi = _flow_events_pair()
     model = res.flow.model
     cols, classic = res.flow.flow_columns(), multi.flow.flow_columns()
     for k, m in model.columns().items():
@@ -184,6 +194,40 @@ def test_resident_slot_minor_columns_over_flow_events():
     # RST teardown empties a slot that still holds its counters
     assert ((model.se[:, 0] == jaxpath.FLOW_EMPTY)
             & (model.cnt[:, 0] > 0)).any()
+    res.close()
+    multi.close()
+
+
+def test_resident_packed_state_age_occupancy_reset():
+    """After the same flow events, the age sweep, the occupancy count
+    and the reset read and write the packed matrix as the host model
+    does: equal aged counts on the resident and the classic tier and in
+    a copy of the model, equal occupancy, equal columns after each."""
+    import copy
+
+    res, multi = _flow_events_pair()
+    model = res.flow.model
+    live = model.se[:, 0] > 0
+    # entries seen before the last step expire, those seen in it stay
+    cutoff = int(model.se[live, 1].max())
+    horizon = res.flow.epoch - cutoff
+    assert res.flow.epoch == multi.flow.epoch and horizon >= 0
+    shadow = copy.deepcopy(model)
+    want = shadow.age(cutoff)
+    assert res.flow.age(horizon) == multi.flow.age(horizon) == want > 0
+    occ = shadow.occupancy()
+    assert 0 < occ < int(live.sum())
+    for tier in (res.flow, multi.flow):
+        assert tier.occupancy() == tier.model.occupancy() == occ
+        cols = tier.flow_columns()
+        for k, m in shadow.columns().items():
+            assert cols[k].dtype == m.dtype, k
+            assert np.array_equal(cols[k], m), f"aged column {k} != model"
+        tier.reset()
+        assert tier.occupancy() == 0
+        for k, m in tier.model.columns().items():
+            assert not m.any(), k
+            assert np.array_equal(tier.flow_columns()[k], m), k
     res.close()
     multi.close()
 

@@ -84,6 +84,39 @@ DENSE_LPM = {"lpm"}
 WALK_LPM = {"lpm_root", "lpm_level", "lpm_resolve"}
 
 
+#: gathers and scatters of the flow tier in one resident step: the
+#: probe's way-gather and its counter add, last-seen max and RST min
+#: scatters; the insert's way-gather, winner max and seed add scatters,
+#: its gather of both at the chosen slot and its one set scatter
+FLOW_GATHERS_SCATTERS = 9
+
+
+@pytest.mark.parametrize("path,deep,k", [("dense", False, 0),
+                                         ("trie", True, 0),
+                                         ("dense", False, 2)],
+                         ids=["dense", "trie", "superbatch"])
+def test_flow_tier_gathers_and_scatters(path, deep, k):
+    """The flow state is one packed matrix: the resident step's
+    flow_probe and flow_insert stages hold at most nine gather and
+    scatter instructions (fifteen over four matrices), once per step
+    in the superbatch's loop too, and the matrix stays donated: the
+    step's first output aliases its first operand."""
+    cfg, args = resident_args(256, deep, k)
+    factory = (jaxpath.jitted_resident_superbatch if k
+               else jaxpath.jitted_resident_step)
+    text = factory(cfg.entries, cfg.ways, path).lower(*args).compile().as_text()
+    stages = jaxpath.hlo_stage_map(text)
+    flow = [n for n, op in opcodes(text).items()
+            if op in ("gather", "scatter")
+            and stages[n] in ("flow_probe", "flow_insert")]
+    assert 0 < len(flow) <= FLOW_GATHERS_SCATTERS, flow
+    head = text.splitlines()[0]
+    assert re.search(r"input_output_alias=\{ \{0\}: \(0, \{\}, may-alias\)",
+                     head), head[:300]
+    state = f"s32[{jaxpath.FLOW_ROWS},{cfg.capacity}]"
+    assert f"entry_computation_layout={{({state}" in head
+
+
 @pytest.mark.parametrize("path,deep,k,lpm", [("dense", False, 0, DENSE_LPM),
                                              ("trie", True, 0, WALK_LPM),
                                              ("dense", False, 2, DENSE_LPM)],
